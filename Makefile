@@ -25,8 +25,11 @@ bench:
 # zero steady-state compiles by check_regression --drift), and writes the
 # telemetry-on request trace to BENCH_serve_trace.json (Chrome-trace JSON;
 # load in https://ui.perfetto.dev).
+# On the CPU the scaling sweep runs over 4 forced host devices (the flag
+# does nothing on an accelerator, where the sweep takes the chips it has).
 bench-serve:
-	$(PY) -m benchmarks.run --only serve_stream --json BENCH_serve.json
+	XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+	    $(PY) -m benchmarks.run --only serve_stream --json BENCH_serve.json
 
 # regression gate: re-run the serving bench and compare against the
 # committed baseline (fails on a >15% tok/s drop, a speculative-decode

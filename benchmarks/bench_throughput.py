@@ -28,17 +28,12 @@ certificate (`check_regression --drift` gates measured <= scale * bound),
 and the sentinel's saturated-decode overhead (gated <= 2%, zero steady-state
 compiles — every shadow-path executable is warmed in warmup()).
 Scaling rows (`serve_stream.scaling`): saturated-decode throughput of the
-sharded slot pool vs device count. Device counts are forced host (CPU)
-devices, so the curve verifies layout/overhead scaling (no cross-shard
-chatter, zero steady-state compiles), not hardware speedup — each
-subprocess sets --xla_force_host_platform_device_count before importing
-jax, which is why the sweep cannot run in this process.
+sharded slot pool vs device count, over the devices of this process (one
+process per chip: a child could not reach a chip this process holds). Run
+with XLA_FLAGS=--xla_force_host_platform_device_count=N to sweep forced
+host devices on the CPU, which verifies layout and zero steady-state
+compiles, not hardware speedup.
 """
-import json
-import os
-import subprocess
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -90,7 +85,7 @@ GEN_TOKENS = (16, 48)
 N_SLOTS, MAX_LEN = 4, 192
 PREFILL_BATCH = 2
 SPEC_K = "auto"                         # speculative case: autotuned config
-SCALE_DEVICES = (1, 2, 4)               # slot-pool shard sweep (CPU mesh)
+SCALE_DEVICES = (1, 2, 4)               # slot-pool shard sweep
 SCALE_SLOTS = 8                         # divisible by every count above
 
 
@@ -268,53 +263,23 @@ def _sentinel_case(cfg, params):
     }
 
 
-# run in a fresh interpreter per device count: the device count is fixed
-# before jax imports. Prints one "RESULT {json}" line on success.
-_SCALE_SNIPPET = """
-import json
-import jax, numpy as np
-from benchmarks.bench_throughput import MAX_LEN, SCALE_SLOTS
-from benchmarks.models import build, hyena_cfg
-from repro.launch.mesh import make_slot_mesh
-from repro.serve.metrics import count_compiles
-from repro.serve.scheduler import (ContinuousBatchingEngine,
-                                   measure_saturated_decode)
-
-d = {devices}
-cfg = hyena_cfg()
-params = build(cfg, distill=True)
-mesh = make_slot_mesh(d) if d > 1 else None
-eng = ContinuousBatchingEngine(params, cfg, n_slots=SCALE_SLOTS,
-                               max_len=MAX_LEN, mode="distilled", mesh=mesh)
-eng.warmup((32,))
-with count_compiles() as scope:
-    m = measure_saturated_decode(eng, prompt_len=32)
-print("RESULT " + json.dumps({{
-    "devices": d,
-    "n_shards": eng._n_shards,
-    "decode_sat_tok_per_s": m["decode_tok_per_s"],
-    "steady_state_compiles": scope.compiles,
-}}))
-"""
-
-
 def _scale_case(devices: int):
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(
-        os.environ,
-        XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
-        PYTHONPATH=os.pathsep.join(
-            p for p in (root, os.path.join(root, "src"),
-                        os.environ.get("PYTHONPATH")) if p))
-    env.pop("REPRO_SLOT_MESH", None)
-    p = subprocess.run([sys.executable, "-c",
-                        _SCALE_SNIPPET.format(devices=devices)],
-                       capture_output=True, text=True, env=env, timeout=1200)
-    for line in reversed(p.stdout.splitlines()):
-        if line.startswith("RESULT "):
-            return json.loads(line[len("RESULT "):])
-    tail = (p.stdout + p.stderr)[-2000:]
-    return {"devices": devices, "error": f"rc={p.returncode}: {tail}"}
+    """Saturated decode of the slot pool sharded over the first `devices`
+    devices of this process (single-device engine at 1)."""
+    from repro.launch.mesh import make_slot_mesh
+    from repro.serve.metrics import count_compiles
+    cfg = hyena_cfg()
+    params = build(cfg, distill=True)
+    mesh = make_slot_mesh(devices) if devices > 1 else None
+    eng = ContinuousBatchingEngine(params, cfg, n_slots=SCALE_SLOTS,
+                                   max_len=MAX_LEN, mode="distilled",
+                                   mesh=mesh)
+    eng.warmup((32,))
+    with count_compiles() as scope:
+        m = measure_saturated_decode(eng, prompt_len=32)
+    return {"devices": devices, "n_shards": eng._n_shards,
+            "decode_sat_tok_per_s": m["decode_tok_per_s"],
+            "steady_state_compiles": scope.compiles}
 
 
 def stream_main(out):
@@ -381,19 +346,16 @@ def stream_main(out):
             f"overhead={sent['overhead_frac'] * 100:+.2f}% "
             f"checks={sent['drift_checks']} "
             f"compiles_in_run={sent['steady_state_compiles']}"))
-    # tok/s-vs-devices scaling of the sharded slot pool (fresh interpreter
-    # per device count — see _SCALE_SNIPPET)
-    scaling = [_scale_case(d) for d in SCALE_DEVICES]
+    # tok/s-vs-devices scaling of the sharded slot pool, in this process
+    # over the devices it has
+    scaling = [_scale_case(d) for d in SCALE_DEVICES
+               if d <= jax.device_count()]
     results["scaling"] = {"n_slots": SCALE_SLOTS, "devices": scaling}
     for s in scaling:
-        if "error" in s:
-            out(row(f"serve_stream/scaling/d{s['devices']}", 0.0,
-                    f"ERROR {s['error'][:120]}"))
-        else:
-            out(row(f"serve_stream/scaling/d{s['devices']}", 0.0,
-                    f"sat_decode_tok_s={s['decode_sat_tok_per_s']:.0f} "
-                    f"shards={s['n_shards']} "
-                    f"compiles_in_run={s['steady_state_compiles']}"))
+        out(row(f"serve_stream/scaling/d{s['devices']}", 0.0,
+                f"sat_decode_tok_s={s['decode_sat_tok_per_s']:.0f} "
+                f"shards={s['n_shards']} "
+                f"compiles_in_run={s['steady_state_compiles']}"))
     return {"serve_stream": results}
 
 

@@ -18,6 +18,7 @@ sys.path.insert(0, "src")
 from benchmarks import (bench_distill, bench_kernels, bench_memory,
                         bench_prefill_strategies, bench_prompt_scaling,
                         bench_state_dim, bench_throughput)
+from repro.launch.compile_cache import enable_compile_cache
 
 SUITES = {
     "fig1.1_throughput": bench_throughput.main,
@@ -38,6 +39,7 @@ def main() -> None:
     ap.add_argument("--json", type=str, default=None,
                     help="write structured suite metrics to this file")
     args = ap.parse_args()
+    enable_compile_cache()
     print("name,us_per_call,derived")
     rows = []
     data = {}

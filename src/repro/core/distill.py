@@ -39,12 +39,12 @@ def kung_poles(h: jnp.ndarray, d: int) -> jnp.ndarray:
     S = hankel_matrix(h).astype(jnp.float32)
     m = S.shape[-1]
     dd = min(2 * d, m - 1)
-    U, s, _ = jnp.linalg.svd(S, full_matrices=False)
-    Od = U[..., :, :dd] * jnp.sqrt(s[..., None, :dd] + 1e-30)
+    U, s = top_singular_pairs(S, dd)
+    Od = U * jnp.sqrt(s[..., None, :] + 1e-30)
     O1 = Od[..., :-1, :]
     O2 = Od[..., 1:, :]
     A = jnp.linalg.pinv(O1) @ O2                           # (..., 2d, 2d)
-    lam = jnp.linalg.eigvals(A)
+    lam = host_eigvals(A)
     mag = jnp.clip(jnp.abs(lam), 1e-4, 1.2)
     ang = jnp.angle(lam)
     # jitter the phases so coincident true poles don't make the LSQ singular
@@ -60,6 +60,53 @@ def kung_poles(h: jnp.ndarray, d: int) -> jnp.ndarray:
     infl = jnp.where(upper, infl, -1.0)
     idx = jnp.argsort(-infl, axis=-1)[..., :d]
     return jnp.take_along_axis(lam, idx, axis=-1)
+
+
+# block subspace iteration of top_singular_pairs: extra columns beyond the
+# k wanted, and power steps
+_OVERSAMPLE, _POWER_ITERS = 16, 8
+
+
+def top_singular_pairs(S: jnp.ndarray, k: int
+                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Leading k left singular vectors (..., m, k) and values (..., k) of
+    the square Hankel matrices S (..., m, m), by orthonormalized subspace
+    iteration on a k + _OVERSAMPLE block, then a dense SVD of the small
+    projected block.
+
+    Kung's method only reads the top 2d singular triplets. A dense SVD of
+    the whole (m, m) matrix costs O(m^3), and the TPU's dense SVD/eigh
+    lowering takes minutes to compile from m ~ 1024 on; the block iteration
+    costs O(m^2 (k + _OVERSAMPLE)) per step and compiles in seconds. The
+    block has at most m columns, so a Hankel of rank <= k + _OVERSAMPLE (an
+    exact low-order filter) is recovered exactly."""
+    m = S.shape[-1]
+    p = min(m, k + _OVERSAMPLE)
+    omega = jax.random.normal(jax.random.PRNGKey(0), (m, p), S.dtype)
+    Q, _ = jnp.linalg.qr(S @ omega)
+
+    def power(Q, _):
+        # S is symmetric (S[i, j] = h[i + j + 1]), so S @ S^T is S @ S
+        Q, _ = jnp.linalg.qr(S @ (S @ Q))
+        return Q, None
+
+    Q, _ = jax.lax.scan(power, Q, None, length=_POWER_ITERS)
+    Ub, s, _ = jnp.linalg.svd(jnp.swapaxes(Q, -1, -2) @ S,
+                              full_matrices=False)
+    return (Q @ Ub)[..., :k], s[..., :k]
+
+
+def host_eigvals(A: jnp.ndarray) -> jnp.ndarray:
+    """Eigenvalues of real square matrices A (..., n, n) as complex64,
+    computed on the host by LAPACK through a callback: XLA has no
+    nonsymmetric eigensolver for the TPU. These are the tiny (2d x 2d) shift
+    matrices of Kung's method, set-up work once per distillation, so the
+    round trip costs nothing measurable."""
+    def eig(a):
+        return np.linalg.eigvals(np.asarray(a)).astype(np.complex64)
+
+    out = jax.ShapeDtypeStruct(A.shape[:-1], jnp.complex64)
+    return jax.pure_callback(eig, out, A, vmap_method="broadcast_all")
 
 
 def fit_residues(lam: jnp.ndarray, h: jnp.ndarray) -> jnp.ndarray:
@@ -162,6 +209,23 @@ def distill_filters(h: jnp.ndarray, d: int, *, steps: int = 3000,
     return out, trace
 
 
+@functools.partial(jax.jit, static_argnames=("hcfg", "L", "d", "steps",
+                                             "objective", "init"))
+def _distill_layer(filter_params, hcfg, L: int, d: int, steps: int,
+                   objective: str, init: str):
+    """Distill one layer's filters: returns (distilled params, per-filter
+    relative l2 error at length L)."""
+    from repro.models.hyena import materialize_filters
+    h, bias = materialize_filters(filter_params, L, hcfg)
+    ssm, _ = distill_filters(h, d, steps=steps, objective=objective,
+                             init=init)
+    dp = {"log_a": ssm.log_a, "theta": ssm.theta,
+          "R_re": ssm.R_re, "R_im": ssm.R_im, "h0": ssm.h0 + bias}
+    err = jnp.sqrt(jnp.sum((eval_filter(ssm, L) - h) ** 2, -1) /
+                   jnp.sum(h * h, -1).clip(1e-30))
+    return dp, err
+
+
 def distill_model(params, cfg, *, d: Optional[int] = None, steps: int = 3000,
                   objective: str = "l2", init: str = "kung", L: Optional[int] = None):
     """Distill every Hyena filter of a model in-place (returns new params).
@@ -171,8 +235,10 @@ def distill_model(params, cfg, *, d: Optional[int] = None, steps: int = 3000,
     modal SSMs, and writes them into params[...]["distilled"] in the layout
     hyena_decode expects. The passthrough absorbs the explicit Hyena bias:
     h0_total = h[0] + bias (both act as delta terms in the block).
+
+    Layers are distilled one at a time through one compiled per-layer fit,
+    so peak memory is one layer's Hankel batch, not the whole model's.
     """
-    from repro.models.hyena import materialize_filters
     from repro.configs.base import HYENA
 
     hcfg = cfg.hyena
@@ -180,29 +246,18 @@ def distill_model(params, cfg, *, d: Optional[int] = None, steps: int = 3000,
     # conjugate-pair representatives (App. B.1).
     d = (d or hcfg.distill_order) // 2
     L = L or min(cfg.max_seq, 8192)
-    n_groups = cfg.n_layers // len(cfg.pattern)
-
-    def distill_entry(block_params):
-        h, bias = materialize_filters(block_params["filter"], L, hcfg)
-        ssm, trace = distill_filters(h, d, steps=steps, objective=objective,
-                                     init=init)
-        dp = {
-            "log_a": ssm.log_a, "theta": ssm.theta,
-            "R_re": ssm.R_re, "R_im": ssm.R_im,
-            "h0": ssm.h0 + bias,
-        }
-        err = jnp.sqrt(jnp.sum((eval_filter(ssm, L) - h) ** 2, -1) /
-                       jnp.sum(h * h, -1).clip(1e-30))
-        return dp, err
 
     new_params = jax.tree.map(lambda x: x, params)   # shallow copy
     errs = {}
     for i, kind in enumerate(cfg.pattern):
         if kind != HYENA:
             continue
-        gp = params["groups"][f"l{i}"]["mix"]
-        # vmap over the stacked group axis
-        dp, err = jax.vmap(distill_entry)(gp)
+        filt = params["groups"][f"l{i}"]["mix"]["filter"]
+        n_stack = jax.tree.leaves(filt)[0].shape[0]
+        outs = [_distill_layer(jax.tree.map(lambda x, g=g: x[g], filt), hcfg,
+                               L, d, steps, objective, init)
+                for g in range(n_stack)]
+        dp, err = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
         new_params["groups"][f"l{i}"]["mix"]["distilled"] = dp
         errs[f"l{i}"] = err
     return new_params, errs

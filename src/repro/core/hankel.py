@@ -11,7 +11,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 
 def hankel_matrix(h: jnp.ndarray) -> jnp.ndarray:
@@ -21,10 +20,11 @@ def hankel_matrix(h: jnp.ndarray) -> jnp.ndarray:
     operator. Output: (..., m, m) with m = (L - 1 + 1) // 2 so every entry is
     defined from available samples.
     """
-    L = h.shape[-1]
-    m = L // 2
-    i = np.arange(m)[:, None] + np.arange(m)[None, :] + 1
-    return h[..., i]
+    m = h.shape[-1] // 2
+    # row i is the window h[i+1 : i+1+m]: a batched dynamic slice, so the
+    # program holds no (m, m) index constant
+    rows = jax.vmap(lambda i: jax.lax.dynamic_slice_in_dim(h, i + 1, m, -1))
+    return jnp.moveaxis(rows(jnp.arange(m)), 0, -2)
 
 
 def hankel_singular_values(h: jnp.ndarray) -> jnp.ndarray:

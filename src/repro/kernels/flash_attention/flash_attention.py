@@ -20,13 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # TPU-only helpers; fall back for interpret mode on CPU
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
@@ -107,21 +101,9 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
         h = bh % Hq
         return (b * Hkv + h // G, kj, 0)
 
-    scratch_shapes = None
-    kwargs = {}
-    if _VMEM is not None:
-        kwargs["scratch_shapes"] = [
-            _VMEM((qb,), jnp.float32),
-            _VMEM((qb,), jnp.float32),
-            _VMEM((qb, hd), jnp.float32),
-        ]
-    else:  # pragma: no cover
-        from jax.experimental.pallas import MemorySpace
-        kwargs["scratch_shapes"] = [
-            pl.MemoryRef((qb,), jnp.float32),
-            pl.MemoryRef((qb,), jnp.float32),
-            pl.MemoryRef((qb, hd), jnp.float32),
-        ]
+    scratch_shapes = [pltpu.VMEM((qb,), jnp.float32),
+                      pltpu.VMEM((qb,), jnp.float32),
+                      pltpu.VMEM((qb, hd), jnp.float32)]
 
     out = pl.pallas_call(
         functools.partial(_kernel, qb=qb, kb=kb, causal=causal, window=window,
@@ -133,6 +115,6 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
         out_specs=pl.BlockSpec((1, qb, hd), q_map),
         out_shape=jax.ShapeDtypeStruct((B * Hq, S, hd), q.dtype),
         interpret=interpret,
-        **kwargs,
+        scratch_shapes=scratch_shapes,
     )(qt, kt, vt)
     return jnp.moveaxis(out.reshape(B, Hq, S, hd), 1, 2)

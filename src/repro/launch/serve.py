@@ -48,6 +48,7 @@ import numpy as np
 from repro.configs import get_config, smoke_config
 from repro.core.distill import distill_model
 from repro.distributed.sharding import unzip
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import init_params
 from repro.serve.engine import GenerationEngine
 from repro.serve.scheduler import (ContinuousBatchingEngine, SamplingParams,
@@ -60,7 +61,7 @@ def _spec_k_arg(v: str):
     return v if v == "auto" else int(v)
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -88,6 +89,9 @@ def main():
     ap.add_argument("--prompt-lens", type=str, default=None,
                     help="comma list of prompt lengths (default: "
                          "prompt-len/2,prompt-len)")
+    ap.add_argument("--max-len", type=int, default=None,
+                    help="slot capacity in tokens (default: longest prompt "
+                         "+ --gen)")
     # serving fast path
     ap.add_argument("--no-bucket", action="store_true",
                     help="disable prompt-length bucketing (compile one "
@@ -155,8 +159,12 @@ def main():
     ap.add_argument("--events-limit", type=int, default=256,
                     help="ring-buffer capacity of the recovery-event log "
                          "(0 = unbounded)")
-    args = ap.parse_args()
+    return ap
 
+
+def load_model(args):
+    """Config + params (seeded init, optional checkpoint restore, optional
+    distillation). Returns (cfg, params)."""
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke_config(cfg)
@@ -171,13 +179,24 @@ def main():
         order = args.distill_order or cfg.hyena.distill_order
         params, errs = distill_model(params, cfg, d=order)
         worst = max(float(jnp.max(e)) for e in errs.values())
-        print(f"[serve] distilled filters to order {order} in "
-              f"{time.time()-t0:.1f}s (worst rel l2 err {worst:.3e})")
+        print(f"[serve] distilled {cfg.n_layers} layers (d_model "
+              f"{cfg.d_model}) to order {order} in {time.time()-t0:.1f}s "
+              f"(worst rel l2 err {worst:.3e})")
+    return cfg, params
 
+
+def main():
+    args = build_parser().parse_args()
+    enable_compile_cache()
+    cfg, params = load_model(args)
     if args.stream:
-        _serve_stream(params, cfg, args)
+        _, m = serve_stream(params, cfg, args)
+        problems = stream_problems(m, args)
+        if problems:
+            raise SystemExit(f"[serve] FAILED: {'; '.join(problems)}")
         return
 
+    key = jax.random.PRNGKey(args.seed)
     engine = GenerationEngine(params, cfg,
                               max_len=args.prompt_len + args.gen,
                               mode=args.mode)
@@ -193,12 +212,32 @@ def main():
     print(toks[0][:16])
 
 
-def _serve_stream(params, cfg, args):
+def stream_problems(m, args):
+    """Why a served stream counts as failed: any ERROR completion or any
+    dispatch fault, unless a fault schedule injected them on purpose."""
+    if args.fault_schedule:
+        return []
+    out = []
+    if m["n_errors"]:
+        out.append(f"{m['n_errors']} of {m['n_requests']} requests ended in "
+                   f"ERROR")
+    if m["resilience"].get("dispatch_faults"):
+        out.append(f"{m['resilience']['dispatch_faults']} dispatch faults")
+    return out
+
+
+def serve_stream(params, cfg, args, *, mesh=None):
+    """Continuous-batching request stream: build the engine (its slot pool
+    sharded over `mesh` when given), warm it up, replay a seeded Poisson
+    stream and print the report. Returns (engine, metrics); the metrics add
+    `warmup_s` and `stream_compiles` (XLA compiles inside the replayed
+    stream) to run_request_stream's."""
+    from repro.serve.metrics import count_compiles
     if args.prompt_lens:
         plens = tuple(int(x) for x in args.prompt_lens.split(","))
     else:
         plens = (max(args.prompt_len // 2, 4), args.prompt_len)
-    max_len = max(plens) + args.gen
+    max_len = args.max_len or max(plens) + args.gen
     injector = None
     if args.fault_schedule:
         from repro.serve.faults import FaultInjector
@@ -211,7 +250,7 @@ def _serve_stream(params, cfg, args):
         tracer = Tracer()
     eng = ContinuousBatchingEngine(params, cfg, n_slots=args.slots,
                                    max_len=max_len, mode=args.mode,
-                                   seed=args.seed,
+                                   seed=args.seed, mesh=mesh,
                                    bucket_prompts=not args.no_bucket,
                                    prefill_chunk=args.chunk,
                                    overlap=not args.sync_loop,
@@ -253,14 +292,20 @@ def _serve_stream(params, cfg, args):
           f"{', chunk=%d' % args.chunk if args.chunk else ''}, "
           f"{'overlapped' if not args.sync_loop else 'sync'} loop"
           f"{spec_desc}) ...")
+    t0 = time.time()
     eng.warmup(plens)
+    warmup_s = time.time() - t0
+    print(f"[serve] warmup compiled in {warmup_s:.1f}s")
     sampling = SamplingParams(temperature=args.temperature, top_k=args.top_k,
                               top_p=args.top_p)
     stream = synthesize_request_stream(
         np.random.default_rng(args.seed), args.n_requests, rate=args.rate,
         prompt_lens=plens, gen_tokens=(max(args.gen // 2, 1), args.gen),
         vocab=cfg.vocab, sampling=sampling)
-    m = run_request_stream(eng, stream)
+    with count_compiles() as scope:
+        m = run_request_stream(eng, stream)
+    m["warmup_s"] = warmup_s
+    m["stream_compiles"] = scope.compiles
     print(f"[serve] mode={args.mode} slots={args.slots} "
           f"{int(m['n_requests'])} requests / {int(m['n_tokens'])} tokens "
           f"in {m['wall_s']:.2f}s")
@@ -292,7 +337,8 @@ def _serve_stream(params, cfg, args):
               f"{args.drift_tol if args.drift_tol is not None else 'off'}), "
               f"final mode {eng.mode}")
     print(f"[serve] scheduler stats: {eng.stats}")
-    print(f"[serve] prefill compile stats: {eng.prefill_compile_stats()}")
+    print(f"[serve] prefill compile stats: {eng.prefill_compile_stats()}; "
+          f"{m['stream_compiles']} compiles inside the stream")
     res = {k: v for k, v in m["resilience"].items() if v}
     if res or m["n_errors"]:
         print(f"[serve] resilience: {m['n_errors']} error completions, "
@@ -313,6 +359,7 @@ def _serve_stream(params, cfg, args):
               f"https://ui.perfetto.dev")
     if server is not None:
         server.shutdown()
+    return eng, m
 
 
 if __name__ == "__main__":

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from repro.configs import get_config, smoke_config
 from repro.data.pipeline import SyntheticLM, MemmapTokens, make_batches
 from repro.distributed.sharding import TRAIN_RULES, tree_shardings, unzip
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models.model import init_params
 from repro.train.checkpoint import Checkpointer
@@ -45,6 +46,7 @@ def main():
     ap.add_argument("--model-par", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -37,18 +37,45 @@ from repro.serve.sampling import sample_token
 _JIT_CACHE: Dict = {}
 
 
+def _per_slot_shard(step, out_shardings):
+    """Run a pooled step once per slot shard (shard_map over the pool's
+    mesh): params and any trailing arguments replicated, cache rows and
+    tokens split along the slot axis, so each device advances only its own
+    slots with no communication. GSPMD cannot partition a Pallas (Mosaic)
+    kernel, so the decode kernel needs this on a sharded pool."""
+    from jax.sharding import PartitionSpec as P
+    from repro.distributed.sharding import shard_map_compat
+    cache_sh, slot_sh = out_shardings[0], out_shardings[1]
+    cache_spec = jax.tree.map(lambda s: s.spec, cache_sh)
+    out_specs = jax.tree.map(lambda s: s.spec, out_shardings)
+
+    def run(params, cache, tokens, *rest, conv_filters=None):
+        def local(p, c, t, cf, *r):
+            return step(p, c, t, *r, conv_filters=cf)
+        in_specs = (P(), cache_spec, slot_sh.spec, P()) + (P(),) * len(rest)
+        return shard_map_compat(local, slot_sh.mesh, in_specs, out_specs)(
+            params, cache, tokens, conv_filters, *rest)
+    return run
+
+
+def _jit_pooled(step, out_shardings):
+    if out_shardings is None:
+        return jax.jit(step, donate_argnums=(1,))
+    return jax.jit(_per_slot_shard(step, out_shardings), donate_argnums=(1,),
+                   out_shardings=out_shardings)
+
+
 def jitted_decode_step(cfg: ModelConfig, ctx: ShardCtx = NOCTX, *,
                        out_shardings=None, shard_key=None):
     """`out_shardings` pins the (cache, logits) output shardings for a
     sharded slot pool — the layout never drifts between ticks, so the
-    steady state stays at zero recompiles. `shard_key` distinguishes the
-    sharded executable from the single-device one in the shared memo."""
+    steady state stays at zero recompiles — and runs the step per slot
+    shard (`_per_slot_shard`). `shard_key` distinguishes the sharded
+    executable from the single-device one in the shared memo."""
     key = ("decode", cfg, id(ctx), shard_key)
     if key not in _JIT_CACHE:
-        kw = {} if out_shardings is None else {"out_shardings": out_shardings}
-        _JIT_CACHE[key] = jax.jit(
-            functools.partial(decode_step, cfg=cfg, ctx=ctx),
-            donate_argnums=(1,), **kw)
+        _JIT_CACHE[key] = _jit_pooled(
+            functools.partial(decode_step, cfg=cfg, ctx=ctx), out_shardings)
     return _JIT_CACHE[key]
 
 
@@ -69,10 +96,9 @@ def jitted_decode_step_guarded(cfg: ModelConfig, ctx: ShardCtx = NOCTX, *,
     `out_shardings`/`shard_key`: see `jitted_decode_step`."""
     key = ("decode_guarded", cfg, id(ctx), shard_key)
     if key not in _JIT_CACHE:
-        kw = {} if out_shardings is None else {"out_shardings": out_shardings}
-        _JIT_CACHE[key] = jax.jit(
+        _JIT_CACHE[key] = _jit_pooled(
             functools.partial(_decode_step_guarded, cfg=cfg, ctx=ctx),
-            donate_argnums=(1,), **kw)
+            out_shardings)
     return _JIT_CACHE[key]
 
 

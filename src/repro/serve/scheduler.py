@@ -1305,10 +1305,13 @@ class ContinuousBatchingEngine:
         upload goes through `_put_slot_vec`, so on a sharded pool each
         device receives only its own row block — a plain `jnp.asarray`
         would land the vector committed to device 0 and force an all-to-one
-        layout change inside the next spec round."""
+        layout change inside the next spec round. The mirror is uploaded
+        as a copy: the CPU client may alias an aligned numpy buffer
+        zero-copy, and the controller rewrites the mirror while the
+        overlapped loop still has this round queued."""
         if not np.array_equal(self._spec_win, self._spec_win_dev):
             self._spec_len = self._put_slot_vec(
-                np.asarray(self._spec_win, np.int32))
+                np.array(self._spec_win, np.int32))
             self._spec_win_dev[:] = self._spec_win
             self._bump_stat("spec_window_syncs")
             self.resilience.bump("spec_window_syncs")

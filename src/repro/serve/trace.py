@@ -39,10 +39,7 @@ import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
-try:  # pragma: no cover - availability depends on the jax build
-    from jax.profiler import TraceAnnotation as _TraceAnnotation
-except Exception:  # pragma: no cover
-    _TraceAnnotation = None
+from jax.profiler import TraceAnnotation
 
 # event tuples: (ph, name, cat, pid, tid, t0, dur, args)
 #   ph "X" = complete span (dur in seconds), "i" = instant (dur ignored)
@@ -94,16 +91,12 @@ class _DeviceSpan(_Span):
     __slots__ = ("_ann",)
 
     def __enter__(self):
-        if _TraceAnnotation is not None:
-            self._ann = _TraceAnnotation(self._name)
-            self._ann.__enter__()
-        else:  # pragma: no cover
-            self._ann = None
+        self._ann = TraceAnnotation(self._name)
+        self._ann.__enter__()
         return super().__enter__()
 
     def __exit__(self, *exc):
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
+        self._ann.__exit__(*exc)
         return super().__exit__(*exc)
 
 

@@ -52,6 +52,29 @@ def test_ssm_decode_sweep(B, C, d, bb, cb):
         np.testing.assert_allclose(np.asarray(o), np.asarray(r), atol=2e-5)
 
 
+@pytest.mark.parametrize("B,C", [(8, 864), (12, 864), (8, 2048), (5, 864)])
+def test_ssm_decode_default_blocks(B, C):
+    """Default tiling at served widths (MultiHyena-153M / -1.3B channels,
+    slot counts that are not multiples of 8)."""
+    d = 8
+    params = _modal_params(jax.random.PRNGKey(C), C, d)
+    xr = jax.random.normal(jax.random.PRNGKey(1), (B, C, d))
+    xi = jax.random.normal(jax.random.PRNGKey(2), (B, C, d))
+    u = jax.random.normal(jax.random.PRNGKey(3), (B, C))
+    ref = ssm_decode_ref(xr, xi, u, *params)
+    out = ssm_decode_pallas(xr, xi, u, *params, interpret=True)
+    for r, o in zip(ref, out):
+        np.testing.assert_allclose(np.asarray(o), np.asarray(r), atol=2e-5)
+
+
+def test_ssm_decode_rejects_untileable_block():
+    params = _modal_params(jax.random.PRNGKey(0), 96, 4)
+    x = jnp.zeros((4, 96, 4))
+    with pytest.raises(ValueError, match="multiple of 8"):
+        ssm_decode_pallas(x, x, jnp.zeros((4, 96)), *params, cb=12,
+                          interpret=True)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("B,S,Hq,Hkv,hd,window", [
     (2, 256, 4, 2, 64, 0),

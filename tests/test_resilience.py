@@ -509,3 +509,36 @@ def test_checkpoint_pickles_cleanly(hyena_model, tmp_path):
     else:
         assert state["mesh"]["n_shards"] == eng._n_shards
     assert json.dumps(state["resilience"])  # JSON-serializable counters
+
+
+# ---------------------------------------------------------------------------
+# the serving CLI fails loudly on a genuine (non-injected) fault
+# ---------------------------------------------------------------------------
+def test_serve_stream_reports_genuine_dispatch_fault(hyena_model,
+                                                     monkeypatch):
+    """A real exception inside a decode dispatch is absorbed by the engine
+    (pool rebuilt, residents recovered) but the launcher must still count
+    the stream as failed; an injected fault schedule exempts it."""
+    from repro.launch.serve import build_parser, serve_stream, stream_problems
+    cfg, params = hyena_model
+    args = build_parser().parse_args([
+        "--arch", cfg.name, "--stream", "--slots", "2", "--n-requests", "3",
+        "--prompt-lens", "4,7", "--gen", "4", "--rate", "1000"])
+    clean = serve_stream(params, cfg, args)[1]
+    assert stream_problems(clean, args) == []
+    real = ContinuousBatchingEngine._dispatch_decode
+    calls = {"n": 0}
+
+    def flaky(self):
+        calls["n"] += 1
+        if calls["n"] == 2:
+            raise RuntimeError("device lost")
+        return real(self)
+
+    monkeypatch.setattr(ContinuousBatchingEngine, "_dispatch_decode", flaky)
+    eng, m = serve_stream(params, cfg, args)
+    assert m["resilience"]["dispatch_faults"] == 1
+    assert any("dispatch fault" in p for p in stream_problems(m, args))
+    injected = build_parser().parse_args(
+        ["--arch", cfg.name, "--fault-schedule", '{"events": []}'])
+    assert stream_problems(m, injected) == []
