@@ -168,7 +168,7 @@ class GenerationEngine:
                  ) -> Tuple[jnp.ndarray, Dict]:
         """prompt: (B, T) int32 -> (B, n_tokens) generated ids."""
         tr = self.tracer
-        with tr.device_span("prefill", tokens=int(prompt.shape[-1])):
+        with tr.span("prefill", tokens=int(prompt.shape[-1])):
             cache, last_logits = self._prefill(self.params, prompt,
                                                frontend=frontend)
         toks = []
@@ -178,7 +178,7 @@ class GenerationEngine:
             nxt = sample_token(sub, logits, temperature=temperature,
                                top_k=top_k, top_p=top_p)
             toks.append(nxt)
-            with tr.device_span("decode_step"):
+            with tr.span("decode_step"):
                 cache, logits = self._decode(self.params, cache, nxt[:, None],
                                              conv_filters=self._conv_filters)
             logits = logits[:, 0, :]

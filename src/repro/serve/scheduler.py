@@ -75,7 +75,7 @@ from repro.serve.metrics import (DRIFT_BUCKETS, MetricsRegistry,
                                  RATIO_BUCKETS, ResilienceCounters,
                                  WINDOW_BUCKETS)
 from repro.serve.sampling import sample_token_slots
-from repro.serve.trace import NULL_TRACER
+from repro.serve.trace import NULL_TRACER, SPANS, stat_key
 from repro.serve.speculative import DRAW_TAG, token_keys
 
 QUEUED, PREFILLING, RUNNING, FINISHED, ERROR = (
@@ -201,10 +201,20 @@ class Request:
     retries: int = 0                         # quarantine re-prefill attempts
     retry_at: int = 0                        # earliest tick for re-admission
     admit_seq: int = -1                      # dispatch seq at latest admission
+    # lifecycle stamps on the engine's clock; the admission ones are set at
+    # the first admission only (a recovery re-prefill keeps them):
+    #   t_dequeued         left the queue for its prefill
+    #   t_prefill_enqueued every device op of its admission enqueued, just
+    #                      before the host waits for its first token
+    #   t_admitted         that wait returned (the first token is on host)
     t_submit: float = math.nan
+    t_dequeued: float = math.nan
+    t_prefill_enqueued: float = math.nan
     t_admitted: float = math.nan
     t_first_token: float = math.nan
     t_finished: float = math.nan
+    prefill_bucket: int = 0                  # padded length it was prefilled at
+    prefill_rows: int = 0                    # real rows in its prefill call
 
     @property
     def prompt_len(self) -> int:
@@ -545,14 +555,16 @@ class ContinuousBatchingEngine:
         self._chunk_state: Optional[Dict[str, Any]] = None
         self._buckets_used: set = set()
         self._next_rid = 0
-        self.t_admit = 0.0                    # host seconds spent admitting
-        self.stats: Dict[str, int] = {"admitted": 0, "evicted": 0,
-                                      "decode_steps": 0, "prefills": 0,
-                                      "prefill_calls": 0, "chunk_steps": 0,
-                                      "spec_rounds": 0, "spec_drafted": 0,
-                                      "spec_accepted": 0,
-                                      "spec_slot_rounds": 0,
-                                      "spec_window_syncs": 0}
+        # event counts, then the host seconds spent in each phase span
+        # (`phase_s_<span>`, serve/trace.py SPANS)
+        self.stats: Dict[str, float] = {"admitted": 0, "evicted": 0,
+                                        "decode_steps": 0, "prefills": 0,
+                                        "prefill_calls": 0, "chunk_steps": 0,
+                                        "spec_rounds": 0, "spec_drafted": 0,
+                                        "spec_accepted": 0,
+                                        "spec_slot_rounds": 0,
+                                        "spec_window_syncs": 0}
+        self.stats.update((stat_key(n), 0.0) for n in SPANS)
         # --- resilience layer (see serve/README.md "Failure handling") ---
         self._tick = 0
         self._dispatch_seq = 0     # monotonic dispatch counter (see _retire)
@@ -842,6 +854,20 @@ class ContinuousBatchingEngine:
     def _use_chunked(self, L: int) -> bool:
         return self._chunk is not None and L > self._chunk
 
+    @property
+    def t_admit(self) -> float:
+        """Host seconds spent in the admission phase (`phase_s_admit`)."""
+        return self.stats["phase_s_admit"]
+
+    @t_admit.setter
+    def t_admit(self, seconds: float) -> None:
+        self.stats["phase_s_admit"] = seconds
+
+    def _span(self, name: str, **args):
+        """A phase span: profiler annotation `serve.<name>`, the
+        `phase_s_<name>` counter and, with a tracer bound, a ring event."""
+        return self.tracer.span(name, stat=self._bump_stat, **args)
+
     def step(self) -> int:
         """One scheduler tick. Overlapped: (1) enqueue the next pooled decode
         (or speculative draft+verify round) from device-resident state,
@@ -852,43 +878,38 @@ class ContinuousBatchingEngine:
         (the original loop). Returns the number of tokens appended to
         requests during this call."""
         self._tick += 1
-        tr = self.tracer
+        with self._span("tick", step=self._tick):
+            return self._step()
+
+    def _step(self) -> int:
         t_step0 = self._clock()
         emitted = 0
         if self._injector is not None:
-            with tr.span("faults"):
+            with self._span("faults"):
                 self._apply_scheduled_faults()
         if self._sentinel and self._tick % self._drift_every == 0:
             # sentinel sync point: retire the in-flight tick first so the
             # host-side token record matches the at-rest device cache
-            with tr.span("drift_check"):
+            with self._span("drift_check"):
                 prev0, self._pending = self._pending, None
                 emitted += self._retire(prev0)
                 self._drift_check()
         dispatch = self._dispatch_spec if self._spec else self._dispatch_decode
         prev, self._pending = self._pending, None
         if self._overlap and self.n_active > 0:
-            with tr.span("dispatch"):
+            with self._span("dispatch"):
                 self._pending = self._safe_dispatch(dispatch)
-        with tr.span("retire"):
+        with self._span("retire"):
             emitted += self._retire(prev)
         if self._any_deadline:
-            with tr.span("deadline_sweep"):
+            with self._span("deadline_sweep"):
                 self._sweep_deadlines()
-        t0 = self._clock()
-        work0 = self.stats["prefill_calls"] + self.stats["chunk_steps"]
-        with tr.span("admit"):
+        with self._span("admit"):
             emitted += self._admit_phase()
-        if self.stats["prefill_calls"] + self.stats["chunk_steps"] > work0:
-            # only admission phases that actually prefilled count toward
-            # t_admit; note that with the overlapped loop part of this host
-            # time still shadows an in-flight device decode, so the derived
-            # decode_tok_per_s is an upper bound on pure-decode throughput
-            self.t_admit += self._clock() - t0
         if not self._overlap and self.n_active > 0:
-            with tr.span("dispatch"):
+            with self._span("dispatch"):
                 pend = self._safe_dispatch(dispatch)
-            with tr.span("retire"):
+            with self._span("retire"):
                 emitted += self._retire(pend)
         # per-tick telemetry: the tick-latency histogram is what the
         # watchdog reads, so its cost is the one clock call either way
@@ -1106,6 +1127,10 @@ class ContinuousBatchingEngine:
         chunked-prefill step + finalize when enabled, the pooled decode step,
         the batched sampler, and the slot-scatter ops. Side effect: idle
         slots advance one (ignored) decode position."""
+        with self._span("warmup"):
+            self._warmup(prompt_lens)
+
+    def _warmup(self, prompt_lens: Sequence[int]) -> None:
         lens = sorted({int(x) for x in prompt_lens})
         direct = [L for L in lens if not self._use_chunked(L)]
         # host-side request-key derivation (fold_in + stack at admission)
@@ -1273,7 +1298,7 @@ class ContinuousBatchingEngine:
         retired after the NEXT dispatch."""
         self._dispatch_seq += 1
         health = None
-        with self.tracer.device_span("decode_step"):
+        with self._span("decode_step"):
             if self._guard and self._tick % self._health_every == 0:
                 # fused variant: the integrity reduction rides the decode
                 # executable — no extra host dispatch on the hot path
@@ -1338,7 +1363,7 @@ class ContinuousBatchingEngine:
         self._dispatch_seq += 1
         self._sync_spec_len()
         K_r = next(L for L in self._spec_levels if L >= need)
-        with self.tracer.device_span("spec_round", depth=K_r):
+        with self._span("spec_round", depth=K_r):
             (self.cache, new_draft, emitted, n_emit, last, tok_idx) = \
                 self._spec_rounds[K_r](
                     self.params, self._draft_params, self.cache,
@@ -1385,12 +1410,11 @@ class ContinuousBatchingEngine:
         if pending is None:
             return 0
         seq, snapshot, toks_dev, n_emit_dev, health_dev = pending
-        toks = np.asarray(toks_dev)
-        n_emit = None if n_emit_dev is None else np.asarray(n_emit_dev)
-        health = None if health_dev is None else np.asarray(health_dev)
+        with self._span("retire.wait"):
+            toks = np.asarray(toks_dev)
+            n_emit = None if n_emit_dev is None else np.asarray(n_emit_dev)
+            health = None if health_dev is None else np.asarray(health_dev)
         emitted = 0
-        tr = self.tracer
-        tr_on = tr.enabled
         for b, req, win in snapshot:
             # slot may have been evicted (and even re-admitted) since this
             # tick was dispatched — its speculative token is dropped (the
@@ -1414,8 +1438,6 @@ class ContinuousBatchingEngine:
             if n_emit is None:
                 self._append_token(b, int(toks[b]))
                 emitted += 1
-                if tr_on:
-                    tr.instant("decode_tick", cat="decode", rid=req.rid)
                 continue
             n = int(n_emit[b])
             applied = 0
@@ -1425,9 +1447,6 @@ class ContinuousBatchingEngine:
                 emitted += 1
                 if self.slots[b] is not req or req.status != RUNNING:
                     break                      # evicted mid-speculation
-            if tr_on and applied:
-                tr.instant("spec_round" if win > 1 else "decode_tick",
-                           cat="decode", rid=req.rid, emitted=applied)
             if req.spec and win > 1:
                 # count only DELIVERED accepted drafts: tokens truncated by
                 # an EOS/max-tokens eviction never reached the request. A
@@ -1513,6 +1532,10 @@ class ContinuousBatchingEngine:
     def _pop_queue(self, indices: List[int]) -> List[Request]:
         picked = set(indices)
         out = [self.queue[i] for i in indices]
+        now = self._clock()
+        for req in out:
+            if math.isnan(req.t_dequeued):
+                req.t_dequeued = now
         self.queue = deque(r for i, r in enumerate(self.queue)
                            if i not in picked)
         return out
@@ -1521,8 +1544,11 @@ class ContinuousBatchingEngine:
                      bucket: Optional[int]) -> int:
         """Prefill `reqs` together and scatter into `slots`. bucket=None is
         the legacy exact-length batch=1 path (bucket_prompts=False)."""
-        dspan = self.tracer.device_span("prefill", n=len(reqs),
-                                        bucket=bucket or 0)
+        padded = self._eff_len(reqs[0]) if bucket is None else bucket
+        for req in reqs:
+            if not req.prefill_rows:
+                req.prefill_bucket, req.prefill_rows = padded, len(reqs)
+        dspan = self._span("prefill", n=len(reqs), bucket=padded)
         if bucket is None:
             with dspan:
                 prompt = jnp.asarray(self._eff_prompt(reqs[0]),
@@ -1604,7 +1630,9 @@ class ContinuousBatchingEngine:
             self._slot_keys, self._tok_idx, self._spec_len,
             jnp.asarray(sl), tj, kj, pj, toks, keyvec,
             jnp.asarray(ti), jnp.asarray(slen))
-        toks_h = np.asarray(toks)
+        enqueued = self._clock()
+        with self._span("admit.wait"):
+            toks_h = np.asarray(toks)
         now = self._clock()
         for j, (req, slot) in enumerate(zip(reqs, slots)):
             # host mirror + shadow of the device window vector stay in sync
@@ -1618,6 +1646,7 @@ class ContinuousBatchingEngine:
             req.slot = slot
             req.admit_seq = self._dispatch_seq
             if math.isnan(req.t_admitted):
+                req.t_prefill_enqueued = enqueued
                 req.t_admitted = now
             self.slots[slot] = req
             self.active[slot] = True
@@ -1651,8 +1680,10 @@ class ContinuousBatchingEngine:
     def _start_chunked(self, req: Request, slot: int) -> None:
         req.status = PREFILLING
         req.slot = slot
-        if math.isnan(req.t_admitted):
-            req.t_admitted = self._clock()
+        if not req.prefill_rows:
+            C = self._chunk
+            req.prefill_bucket = -(-self._eff_len(req) // C) * C
+            req.prefill_rows = 1
         self.slots[slot] = req                  # reserve (not yet active)
         self._chunk_state = {"req": req, "slot": slot,
                              "prompt": self._eff_prompt(req),
@@ -1675,8 +1706,7 @@ class ContinuousBatchingEngine:
         cl = min(C, plen - st["start"])
         buf = np.zeros((1, C), np.int32)
         buf[0, :cl] = prompt[st["start"]:st["start"] + cl]
-        with self.tracer.device_span("prefill_chunk", rid=req.rid,
-                                     start=st["start"]):
+        with self._span("prefill_chunk", rid=req.rid, start=st["start"]):
             st["pcache"], last_logits = self._prefill_chunk(
                 self.params, st["pcache"], jnp.asarray(buf), st["start"],
                 chunk_len=cl, conv_filters=self._chunk_filters)
@@ -1732,7 +1762,8 @@ class ContinuousBatchingEngine:
     def _trace_request(self, req: Request) -> None:
         """Emit the request's lifecycle spans from its own recorded
         timestamps at its terminal transition: queue_wait
-        [t_submit, t_admitted], prefill [t_admitted, t_first_token], decode
+        [t_submit, t_dequeued], prefill [t_dequeued, t_first_token] (host
+        glue, the device work and the wait for the first token), decode
         [t_first_token, t_finished], plus a `retire` instant. TTFT is
         queue_wait + prefill and end-to-end latency is the full span chain
         — the trace reconstructs the measured numbers exactly, by
@@ -1742,15 +1773,15 @@ class ContinuousBatchingEngine:
         if not tr.enabled or math.isnan(req.t_submit):
             return
         rid, t_end = req.rid, req.t_finished
-        if math.isnan(req.t_admitted):
+        if math.isnan(req.t_dequeued):
             tr.complete("queue_wait", req.t_submit, t_end, rid=rid)
         else:
-            tr.complete("queue_wait", req.t_submit, req.t_admitted, rid=rid)
+            tr.complete("queue_wait", req.t_submit, req.t_dequeued, rid=rid)
             t_first = req.t_first_token
             if math.isnan(t_first):
-                tr.complete("prefill", req.t_admitted, t_end, rid=rid)
+                tr.complete("prefill", req.t_dequeued, t_end, rid=rid)
             else:
-                tr.complete("prefill", req.t_admitted, t_first, rid=rid)
+                tr.complete("prefill", req.t_dequeued, t_first, rid=rid)
                 tr.complete("decode", t_first, t_end, rid=rid,
                             tokens=len(req.tokens))
         tr.instant("retire", rid=rid, ts=t_end, reason=req.finish_reason,

@@ -1,31 +1,42 @@
 """Span-based request-lifecycle tracer for the serving engine.
 
-The scheduler's host loop emits *phase* spans every tick (dispatch, retire,
-admit, deadline sweep, fault application) and *request* spans at each
-request's terminal transition (queue -> prefill -> decode -> retire), built
-from the engine's own recorded timestamps so the exported trace reconstructs
-a request's measured TTFT and end-to-end latency exactly. Recovery events
+The engine opens a *phase* span at every boundary of its host loop (the
+names are `SPANS`, below) and emits *request* spans at each request's
+terminal transition (queue -> prefill -> decode -> retire), built from the
+engine's own recorded timestamps so the exported trace reconstructs a
+request's measured TTFT and end-to-end latency exactly. Recovery events
 (quarantine, re-prefill, engine demotion, ...) land as instant events on the
 affected request's track, so a faulted request's timeline shows *why* it was
 slow.
+
+Every phase span, whether or not a `Tracer` is bound, does three things:
+
+  * enters ``jax.profiler.TraceAnnotation("serve.<name>")`` (the tick uses
+    ``StepTraceAnnotation`` with its tick number), so a profiler capture of
+    the run carries the engine's phases on the same clock as the device
+    ops. A capture is the switch: with none running an annotation costs
+    about a microsecond;
+  * adds its duration in seconds to the engine's per-phase counter
+    ``phase_s_<name>`` (dots as ``_``) through the `stat` callback;
+  * with a `Tracer` bound, records a complete event into the tracer's ring.
 
 Design constraints (the observability overhead gate in
 benchmarks/check_regression.py holds tracing + metrics to <= 2% of
 saturated-decode throughput, with zero steady-state compiles):
 
   * everything is host-side Python — no device work, no jit, no recompiles;
-  * recording one span costs two clock reads and one deque append; events
-    are compact tuples until export;
+  * recording one span costs two clock reads, one annotation and one
+    counter update, plus one deque append with a tracer bound; events are
+    compact tuples until export;
   * the event store is a bounded ring (``capacity`` events, oldest dropped,
     drops counted) so a long-running serve cannot grow without limit;
-  * the disabled path is ``NULL_TRACER`` — a singleton whose methods are
-    no-ops and whose ``span``/``device_span`` return one shared null context
-    manager, so instrumented code pays ~an attribute lookup when tracing is
-    off.
+  * the disabled path is ``NULL_TRACER`` — a singleton whose recording
+    methods are no-ops; its spans still annotate and count.
 
-``device_span`` additionally enters ``jax.profiler.TraceAnnotation``, so a
-``jax.profiler.trace()`` / TensorBoard capture of the same run carries the
-scheduler's phase names alongside the XLA ops.
+A phase span's duration is host time: JAX dispatch is async, so a span
+around a jitted call measures its enqueue, and only the spans around a
+host fetch (``retire.wait``, ``admit.wait``) measure the host blocked on
+the device.
 
 Export is Chrome-trace JSON (``to_chrome_trace()`` / ``save(path)``): open
 the file in Perfetto (https://ui.perfetto.dev) or chrome://tracing. The host
@@ -39,65 +50,68 @@ import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
-from jax.profiler import TraceAnnotation
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 # event tuples: (ph, name, cat, pid, tid, t0, dur, args)
 #   ph "X" = complete span (dur in seconds), "i" = instant (dur ignored)
 HOST_PID = 0        # host-loop phase spans
 REQUEST_PID = 1     # per-request lifecycle tracks (tid = rid)
 
-
-class _NullContext:
-    """Shared no-op context manager for the disabled tracer."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
+# Every phase span the engines open. Nesting within a tick: `dispatch`
+# holds `decode_step` or `spec_round`; `retire` holds `retire.wait` (the
+# fetch of the dispatched tokens and health); `admit` holds `prefill`,
+# `prefill_chunk` and `admit.wait` (the fetch of the first tokens);
+# `drift_check` holds a `retire`'s body. `warmup` lies outside any tick.
+SPANS = ("tick", "faults", "drift_check", "dispatch", "decode_step",
+         "spec_round", "retire", "retire.wait", "deadline_sweep", "admit",
+         "prefill", "prefill_chunk", "admit.wait", "warmup")
+ANNOTATION_PREFIX = "serve."
 
 
-_NULL_CTX = _NullContext()
+def stat_key(name: str) -> str:
+    """The engine's per-phase seconds counter for span `name`."""
+    return "phase_s_" + name.replace(".", "_")
+
+
+_STAT_KEYS = {n: stat_key(n) for n in SPANS}
 
 
 class _Span:
-    """Context manager recording one complete ("X") host-phase span."""
+    """One phase span: a profiler annotation, a seconds counter and, with
+    a tracer bound, a complete ("X") event in the ring."""
 
-    __slots__ = ("_tr", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_tr", "_name", "_cat", "_args", "_stat", "_step", "_ann",
+                 "_t0")
 
-    def __init__(self, tr: "Tracer", name: str, cat: str, args):
+    def __init__(self, tr, name: str, cat: str, args,
+                 stat: Optional[Callable[[str, float], None]],
+                 step: Optional[int]):
         self._tr = tr
         self._name = name
         self._cat = cat
         self._args = args
+        self._stat = stat
+        self._step = step
 
     def __enter__(self):
+        label = ANNOTATION_PREFIX + self._name
+        self._ann = (TraceAnnotation(label) if self._step is None
+                     else StepTraceAnnotation(label, step_num=self._step))
+        self._ann.__enter__()
         self._t0 = self._tr._clock()
         return self
 
     def __exit__(self, *exc):
         tr = self._tr
-        tr._emit(("X", self._name, self._cat, HOST_PID, 0, self._t0,
-                  tr._clock() - self._t0, self._args))
-        return False
-
-
-class _DeviceSpan(_Span):
-    """A host span that also enters a jax.profiler.TraceAnnotation, so a
-    concurrent profiler capture carries the scheduler phase names."""
-
-    __slots__ = ("_ann",)
-
-    def __enter__(self):
-        self._ann = TraceAnnotation(self._name)
-        self._ann.__enter__()
-        return super().__enter__()
-
-    def __exit__(self, *exc):
+        dur = tr._clock() - self._t0
         self._ann.__exit__(*exc)
-        return super().__exit__(*exc)
+        if self._stat is not None:
+            self._stat(_STAT_KEYS.get(self._name) or stat_key(self._name),
+                       dur)
+        if tr.enabled:
+            tr._emit(("X", self._name, self._cat, HOST_PID, 0, self._t0,
+                      dur, self._args))
+        return False
 
 
 class Tracer:
@@ -127,16 +141,13 @@ class Tracer:
         self._events.append(ev)
         self.total += 1
 
-    def span(self, name: str, cat: str = "phase", **args):
-        """Host-phase span context manager (pid 0 / tid 0)."""
-        return _Span(self, name, cat, args or None)
-
-    def device_span(self, name: str, cat: str = "device", **args):
-        """Span around a device dispatch: host span + jax.profiler
-        TraceAnnotation. Note the host duration measures *enqueue* time —
-        JAX dispatch is async, so the device work itself shows up in a
-        profiler capture, not in this span's dur."""
-        return _DeviceSpan(self, name, cat, args or None)
+    def span(self, name: str, cat: str = "phase", *,
+             stat: Optional[Callable[[str, float], None]] = None,
+             step: Optional[int] = None, **args):
+        """Phase span context manager (pid 0 / tid 0; see `_Span`).
+        `stat(key, seconds)` receives its duration under `stat_key(name)`;
+        `step` makes the annotation a StepTraceAnnotation."""
+        return _Span(self, name, cat, args or None, stat, step)
 
     def complete(self, name: str, t0: float, t1: float, *,
                  cat: str = "request", rid: Optional[int] = None,
@@ -219,11 +230,11 @@ class NullTracer:
     total = 0
     dropped = 0
 
-    def span(self, name, cat="phase", **args):
-        return _NULL_CTX
+    _clock = staticmethod(time.monotonic)
 
-    def device_span(self, name, cat="device", **args):
-        return _NULL_CTX
+    def span(self, name, cat="phase", *, stat=None, step=None, **args):
+        """Annotation and seconds counter only: nothing is recorded."""
+        return _Span(self, name, cat, None, stat, step)
 
     def complete(self, name, t0, t1, *, cat="request", rid=None, **args):
         pass

@@ -2,10 +2,13 @@
 tracer and its Chrome-trace export, and the scheduler integration — an
 exported request trace must reconstruct the measured TTFT / end-to-end
 latency exactly, recovery events must land on the affected request's
-timeline, and telemetry-on serving must stay at zero steady-state
-compiles."""
+timeline, a profiler capture must carry every engine phase span, the
+admission stamps must be ordered, and telemetry-on serving must stay at
+zero steady-state compiles."""
+import glob
 import json
 import math
+import os
 import urllib.error
 import urllib.request
 
@@ -22,8 +25,9 @@ from repro.serve.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                  RESILIENCE_KEYS, ResilienceCounters,
                                  count_compiles, jit_cache_size,
                                  speculative_summary, start_metrics_server)
-from repro.serve.scheduler import ContinuousBatchingEngine
-from repro.serve.trace import (HOST_PID, NULL_TRACER, REQUEST_PID, Tracer)
+from repro.serve.scheduler import ContinuousBatchingEngine, Request
+from repro.serve.trace import (ANNOTATION_PREFIX, HOST_PID, NULL_TRACER,
+                               REQUEST_PID, SPANS, Tracer, stat_key)
 
 MAX_LEN = 48
 PROMPT_LENS = (4, 7, 12, 20, 9)
@@ -259,9 +263,14 @@ def test_speculative_summary_no_speculation_is_silent():
 def test_tracer_spans_and_instants():
     t = [0.0]
     tr = Tracer(clock=lambda: t[0])
-    with tr.span("tick", n=1):
+    seconds = {}
+
+    def stat(key, dur):
+        seconds[key] = seconds.get(key, 0.0) + dur
+
+    with tr.span("tick", n=1, stat=stat, step=1):
         t[0] = 1.0
-        with tr.device_span("decode_step"):
+        with tr.span("decode_step", stat=stat):
             t[0] = 3.0
         t[0] = 4.0
     tr.instant("quarantine", rid=7, detail="nan")
@@ -275,6 +284,8 @@ def test_tracer_spans_and_instants():
     assert inst["ph"] == "i" and inst["pid"] == REQUEST_PID and inst["tid"] == 7
     assert comp["ph"] == "X" and comp["dur"] == pytest.approx(2.0)
     assert tr.request_timeline(7) == [comp, inst]   # sorted by timestamp
+    # each span's duration lands in its seconds counter, on the same clock
+    assert seconds == {"phase_s_tick": 4.0, "phase_s_decode_step": 2.0}
 
 
 def test_tracer_ring_bounds():
@@ -314,9 +325,14 @@ def test_chrome_trace_schema(tmp_path):
 
 def test_null_tracer_is_inert(tmp_path):
     assert not NULL_TRACER.enabled
+    seconds = {}
     with NULL_TRACER.span("x"):
-        with NULL_TRACER.device_span("y"):
+        with NULL_TRACER.span("retire.wait",
+                              stat=lambda k, d: seconds.update({k: d})):
             pass
+    # nothing recorded, but the seconds counter still counts
+    assert list(seconds) == ["phase_s_retire_wait"] and seconds[
+        "phase_s_retire_wait"] >= 0.0
     NULL_TRACER.instant("z", rid=1)
     NULL_TRACER.complete("w", 0.0, 1.0, rid=1)
     assert len(NULL_TRACER) == 0 and NULL_TRACER.events() == []
@@ -343,13 +359,19 @@ def traced_run(hyena_model):
 def test_trace_reconstructs_ttft_and_latency(traced_run):
     """queue_wait + prefill spans sum to the measured TTFT; the full span
     chain sums to the measured end-to-end latency — exactly, because the
-    spans are emitted from the Request's own timestamps."""
+    spans are emitted from the Request's own timestamps. queue_wait ends
+    when the request leaves the queue; prefill runs from there to the
+    first token (host glue, device work and the wait for the token)."""
     eng, tracer, reqs = traced_run
     for req in reqs:
         assert req.status == "finished"
         tl = tracer.request_timeline(req.rid)
         spans = {e["name"]: e for e in tl if e["ph"] == "X"}
         assert set(spans) == {"queue_wait", "prefill", "decode"}
+        assert spans["queue_wait"]["dur"] == pytest.approx(
+            req.t_dequeued - req.t_submit, abs=1e-9)
+        assert spans["prefill"]["dur"] == pytest.approx(
+            req.t_first_token - req.t_dequeued, abs=1e-9)
         ttft = spans["queue_wait"]["dur"] + spans["prefill"]["dur"]
         assert ttft == pytest.approx(req.ttft, abs=1e-9)
         total = ttft + spans["decode"]["dur"]
@@ -365,7 +387,101 @@ def test_trace_reconstructs_ttft_and_latency(traced_run):
 def test_host_loop_phase_spans_present(traced_run):
     eng, tracer, _ = traced_run
     host = {e["name"] for e in tracer.events() if e["pid"] == HOST_PID}
-    assert {"dispatch", "retire", "admit", "decode_step", "prefill"} <= host
+    assert {"tick", "dispatch", "retire", "retire.wait", "admit",
+            "decode_step", "prefill", "admit.wait"} <= host
+    # no per-slot decode instants: a request track holds its lifecycle
+    # spans and a retire marker only
+    assert not [e for e in tracer.events() if e["ph"] == "i"
+                and e["name"] in ("decode_tick", "spec_round")]
+
+
+def test_admission_stamps_ordered(traced_run):
+    _, _, reqs = traced_run
+    for r in reqs:
+        assert (r.t_submit <= r.t_dequeued <= r.t_prefill_enqueued
+                <= r.t_admitted <= r.t_first_token), r
+
+
+@pytest.mark.parametrize("lens,rows,bucket", [((5, 7), 2, 8), ((12,), 1, 16)],
+                         ids=["two_rows", "one_row"])
+def test_prefill_bucket_and_rows(hyena_model, lens, rows, bucket):
+    """A request records the padded length and the real rows of the
+    prefill call that admitted it: two same-bucket prompts share one
+    two-row call, a lone prompt fills one row of it."""
+    cfg, params = hyena_model
+    eng = ContinuousBatchingEngine(params, cfg, n_slots=2, max_len=MAX_LEN,
+                                   max_prefills_per_step=2, min_bucket=8)
+    rng = np.random.default_rng(1)
+    reqs = [eng.submit(rng.integers(0, cfg.vocab, n).astype(np.int32),
+                       max_new_tokens=2) for n in lens]
+    eng.step()
+    assert eng.stats["prefill_calls"] == 1
+    for r in reqs:
+        assert (r.prefill_bucket, r.prefill_rows) == (bucket, rows)
+    eng.run()
+    assert [(r.prefill_bucket, r.prefill_rows) for r in reqs] \
+        == [(bucket, rows)] * len(lens)
+
+
+def test_phase_counters_bounded_by_tick(traced_run):
+    """Every span has its seconds counter in `stats` and on /metrics; the
+    phases inside a tick add up to no more than the tick total."""
+    eng, _, _ = traced_run
+    st = eng.stats
+    assert {stat_key(n) for n in SPANS} <= set(st)
+    tick = st["phase_s_tick"]
+    assert tick > 0.0
+    inside = [n for n in SPANS if n not in ("tick", "warmup")]
+    for n in inside:
+        assert 0.0 <= st[stat_key(n)] <= tick, n
+    top = ("faults", "drift_check", "dispatch", "retire", "deadline_sweep",
+           "admit")
+    assert sum(st[stat_key(n)] for n in top) <= tick
+    assert st["phase_s_retire_wait"] <= st["phase_s_retire"]
+    assert (st["phase_s_prefill"] + st["phase_s_admit_wait"]
+            <= st["phase_s_admit"])
+    assert eng.t_admit == st["phase_s_admit"]
+    text = eng.metrics.to_prometheus()
+    assert "serve_phase_s_tick " in text and "serve_phase_s_admit_wait " in text
+
+
+def _profiled_names(logdir):
+    """Every event name in the profiler capture under `logdir`."""
+    from jax.profiler import ProfileData
+    found = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert found, "the profiler wrote no trace"
+    pd = ProfileData.from_file(sorted(found)[-1])
+    return {e.name for plane in pd.planes for line in plane.lines
+            for e in line.events}
+
+
+def test_profiler_capture_holds_every_span(hyena_model, tmp_path):
+    """With no tracer bound, one engine run under a profiler capture
+    writes every `serve.*` phase span: a capture is the switch. The run
+    takes every path: warmup, scripted faults, the drift sentinel,
+    speculation, chunked and bucketed prefill, and deadlines."""
+    cfg, params = hyena_model
+    inj = FaultInjector([{"tick": 2, "kind": "stall", "duration_s": 0.0}],
+                        seed=0)
+    eng = ContinuousBatchingEngine(
+        params, cfg, n_slots=2, max_len=MAX_LEN, spec_k=2, prefill_chunk=16,
+        drift_check_every=2, deadline_s=600.0, fault_injector=inj)
+    assert not eng.tracer.enabled
+    prompts = _prompts(cfg.vocab)
+    with jax.profiler.trace(str(tmp_path)):
+        eng.warmup(PROMPT_LENS)
+        # a lone request that opts out of speculation decodes plainly
+        eng.submit_request(Request(rid=99, prompt=prompts[0],
+                                   max_new_tokens=3, spec=False))
+        eng.run()
+        for p, g in zip(prompts, GEN_LENS):
+            eng.submit(p, max_new_tokens=g)
+        eng.run()
+    names = _profiled_names(str(tmp_path))
+    want = {ANNOTATION_PREFIX + n for n in SPANS}
+    assert want <= names, sorted(want - names)
+    assert all(r.status == "finished" for r in eng.finished)
 
 
 def test_metrics_populated_by_run(traced_run):
