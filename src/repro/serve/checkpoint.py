@@ -176,6 +176,9 @@ def restore_engine(engine, state) -> None:
     engine._spec_win_dev[:] = state["spec_win"]
     engine.active[:] = state["active"]
     engine.slots = list(state["slots"])
+    engine._n_sampled = sum(
+        1 for b, r in enumerate(engine.slots)
+        if r is not None and engine.active[b] and r.sampling.temperature > 0)
     from collections import deque
     engine.queue = deque(state["queue"])
     engine.finished = list(state["finished"])

@@ -13,6 +13,10 @@ paths consume identical key streams per emitted-token position — that is
 what `filter_logits` is factored out for: the speculative verifier applies
 the exact same temperature/top-k/top-p filtering to target and draft
 distributions before rejection sampling.
+
+The top-k and top-p cutoffs come from an exact per-row threshold search
+on the logits' float32 order (`filter_logits`), not from a sort of the
+vocabulary.
 """
 from __future__ import annotations
 
@@ -34,6 +38,37 @@ def sample_token(key, logits, *, temperature: float = 1.0, top_k: int = 0,
         top_p=jnp.full((B,), top_p, jnp.float32))
 
 
+_I32_MIN = -(2 ** 31)
+_I32_MAX = 2 ** 31 - 1
+
+
+def _order_keys(x):
+    """int32 keys that order as the float32 values `x` compare: the bit
+    pattern with the low 31 bits flipped for negatives (so -inf is the
+    lowest), -0.0 folded onto +0.0 (they compare equal), and every NaN at
+    the top (where a descending sort puts it)."""
+    b = jax.lax.bitcast_convert_type(x, jnp.int32)
+    k = jnp.where(b < 0, b ^ _I32_MAX, b)
+    k = jnp.where(x == 0.0, 0, k)
+    return jnp.where(jnp.isnan(x), _I32_MAX, k)
+
+
+def _largest_key(holds, hi):
+    """Per row, the largest int32 t <= hi for which `holds(t)` ((B,) bool,
+    true for every t below some row cutoff) is true, by bisection over the
+    whole int32 range: 32 fixed steps, each one reduction over the row.
+    A row where it holds nowhere above INT32_MIN gets INT32_MIN."""
+    def step(_, lohi):
+        lo, hi = lohi
+        # ceil((lo + hi) / 2) without int32 overflow
+        mid = (lo >> 1) + (hi >> 1) + (((lo & 1) + (hi & 1) + 1) >> 1)
+        ok = holds(mid)
+        return jnp.where(ok, mid, lo), jnp.where(ok, hi, mid - 1)
+
+    lo = jnp.full(hi.shape, _I32_MIN, jnp.int32)
+    return jax.lax.fori_loop(0, 32, step, (lo, hi))[0]
+
+
 def filter_logits(logits, *, temperature, top_k, top_p):
     """Temperature-scaled + top-k/top-p-filtered logits.
 
@@ -43,26 +78,50 @@ def filter_logits(logits, *, temperature, top_k, top_p):
     temperature <= 0 are greedy there and ignore this). Shared by the
     per-slot sampler and the speculative-decoding verifier so the rejection
     test compares the same filtered distributions the sampler uses.
+
+    Each cutoff is found by an exact threshold search on int32 order keys
+    (`_order_keys`), not a sort: top-k keeps every token at or above the
+    largest key with at least k tokens at or above it (the k-th largest
+    value, ties kept); top-p, over the top-k survivors, every token at or
+    above the largest key whose at-or-above probability mass reaches top_p
+    (the value where a descending cumulative sum first reaches top_p, ties
+    kept). Both are the sets a full sort would keep. "Exact" holds up to
+    float32 summation: the masked sums add in another order than a
+    cumulative sum does, so a row whose mass at the cutoff lies within
+    float32 rounding of top_p may keep one value more or less.
     """
     B, V = logits.shape
     temperature = jnp.asarray(temperature, jnp.float32)
     top_k = jnp.asarray(top_k, jnp.int32)
     top_p = jnp.asarray(top_p, jnp.float32)
     lg = logits.astype(jnp.float32) / jnp.clip(temperature, 1e-6)[:, None]
-    # per-row top-k: the k-th largest value is the row's cutoff (k<=0 -> V)
+    key = _order_keys(lg)
+    hi = jnp.max(key, axis=-1)
+
+    def no_cut():
+        return jnp.full((B,), _I32_MIN, jnp.int32)
+
+    # per-row top-k (k <= 0 -> V keeps all); skipped when no row sets it
     k_eff = jnp.where(top_k > 0, jnp.clip(top_k, 1, V), V)
-    srt = jnp.sort(lg, axis=-1)[:, ::-1]
-    kth = jnp.take_along_axis(srt, (k_eff - 1)[:, None], axis=-1)
-    lg = jnp.where(lg < kth, -jnp.inf, lg)
-    # per-row top-p over the filtered logits (mirrors sample_token)
-    srt2 = jnp.sort(lg, axis=-1)[:, ::-1]
-    probs = jax.nn.softmax(srt2, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    cutoff_idx = jnp.sum(cum < top_p[:, None], axis=-1)
-    cutoff = jnp.take_along_axis(srt2, jnp.clip(cutoff_idx, 0, V - 1)[:, None],
-                                 axis=-1)
-    lg = jnp.where((top_p[:, None] < 1.0) & (lg < cutoff), -jnp.inf, lg)
-    return lg
+    t_k = jax.lax.cond(
+        jnp.any(top_k > 0),
+        lambda: _largest_key(
+            lambda t: jnp.sum(key >= t[:, None], axis=-1) >= k_eff, hi),
+        no_cut)
+    lg = jnp.where(key < t_k[:, None], -jnp.inf, lg)
+    # per-row top-p over the top-k survivors (skipped when no row sets
+    # it); the cutoff is at most the row's largest key, so top_p = 0 keeps
+    # the argmax and its ties
+    def top_p_cut():
+        e = jnp.exp(lg - jnp.max(lg, axis=-1, keepdims=True))
+        need = top_p * jnp.sum(e, axis=-1)
+        return _largest_key(
+            lambda t: jnp.sum(jnp.where(key >= t[:, None], e, 0.0), axis=-1)
+            >= need, hi)
+
+    t_p = jax.lax.cond(jnp.any(top_p < 1.0), top_p_cut, no_cut)
+    return jnp.where((top_p[:, None] < 1.0) & (key < t_p[:, None]), -jnp.inf,
+                     lg)
 
 
 def sample_token_slots(key, logits, *, temperature, top_k, top_p):
@@ -98,7 +157,7 @@ def sample_token_slots(key, logits, *, temperature, top_k, top_p):
         sampled = jnp.where(bad, greedy, sampled)
         return jnp.where(temperature <= 0.0, greedy, sampled)
 
-    # all-greedy fast path: skips the sort-based top-k/top-p filter (the
-    # serving hot loop calls this every tick / every draft-scan step)
+    # all-greedy fast path: skips the top-k/top-p filter (the serving hot
+    # loop calls this every tick / every draft-scan step)
     return jax.lax.cond(jnp.all(temperature <= 0.0), lambda _: greedy,
                         sample, None)

@@ -563,8 +563,12 @@ class ContinuousBatchingEngine:
                                         "spec_rounds": 0, "spec_drafted": 0,
                                         "spec_accepted": 0,
                                         "spec_slot_rounds": 0,
-                                        "spec_window_syncs": 0}
+                                        "spec_window_syncs": 0,
+                                        "sampled_decode_steps": 0}
         self.stats.update((stat_key(n), 0.0) for n in SPANS)
+        # resident requests with temperature > 0: a decode dispatch runs the
+        # sampler's top-k/top-p filter iff this is nonzero
+        self._n_sampled = 0
         # --- resilience layer (see serve/README.md "Failure handling") ---
         self._tick = 0
         self._dispatch_seq = 0     # monotonic dispatch counter (see _retire)
@@ -1314,6 +1318,8 @@ class ContinuousBatchingEngine:
                 self._top_ks, self._top_ps)
         self._last = nxt
         self._bump_stat("decode_steps")
+        if self._n_sampled:
+            self._bump_stat("sampled_decode_steps")
         snapshot = [(int(b), self.slots[b], 1)
                     for b in np.nonzero(self.active)[0]]
         try:
@@ -1378,6 +1384,8 @@ class ContinuousBatchingEngine:
             self.draft_cache = new_draft
         self._last, self._tok_idx = last, tok_idx
         self._bump_stat("decode_steps")
+        if self._n_sampled:
+            self._bump_stat("sampled_decode_steps")
         self._bump_stat("spec_rounds")
         snapshot = []
         for b in act:
@@ -1650,6 +1658,7 @@ class ContinuousBatchingEngine:
                 req.t_admitted = now
             self.slots[slot] = req
             self.active[slot] = True
+            self._n_sampled += req.sampling.temperature > 0.0
             self._bump_stat("admitted")
             if resume[j]:
                 continue          # recovery: no new token at re-admission
@@ -1749,6 +1758,9 @@ class ContinuousBatchingEngine:
         temperature or speculation window on a dead row would force the slow
         branch of every jnp.all fast path — greedy sampler, full-accept
         commit)."""
+        req = self.slots[slot]
+        if self.active[slot] and req.sampling.temperature > 0.0:
+            self._n_sampled -= 1
         self.slots[slot] = None
         self.active[slot] = False
         (self._temps, self._top_ks, self._top_ps, self._spec_len) = \

@@ -131,7 +131,11 @@ def test_admission_eviction_bookkeeping(hyena_model):
     prompts = _prompts(cfg.vocab)[:3]
     eng = ContinuousBatchingEngine(params, cfg, n_slots=2, max_len=MAX_LEN,
                                    max_prefills_per_step=2, overlap=False)
-    reqs = [eng.submit(p, max_new_tokens=4) for p in prompts]
+    # the first request samples: the sampled-decode counter covers exactly
+    # the three decode ticks it is resident for
+    reqs = [eng.submit(p, max_new_tokens=4,
+                       sampling=SamplingParams(temperature=float(i == 0)))
+            for i, p in enumerate(prompts)]
     assert [r.status for r in reqs] == ["queued"] * 3
     eng.step()
     # two slots filled, third request still queued; FIFO admission order
@@ -147,6 +151,8 @@ def test_admission_eviction_bookkeeping(hyena_model):
     assert all(r.finish_reason == "max_tokens" for r in reqs)
     assert eng.n_active == 0 and eng.n_free == 2 and not eng.queue
     assert eng.stats["admitted"] == 3 and eng.stats["evicted"] == 3
+    assert eng.stats["sampled_decode_steps"] == 3
+    assert eng.stats["decode_steps"] == 6
     # request 3 reused a slot freed by an earlier eviction
     assert reqs[2].t_admitted >= min(reqs[0].t_finished, reqs[1].t_finished)
 
@@ -193,10 +199,12 @@ def test_request_stream_driver(hyena_model):
 def test_sample_token_slots_per_row_params():
     """Each row honors its own temperature/top-k/top-p."""
     key = jax.random.PRNGKey(0)
+    # the top-3 row's three largest (indices 4, 7, 6) lie close, so its 64
+    # draws spread over them
     logits = jnp.asarray([
         [0.0, 1.0, 2.0, 3.0, 10.0, 4.0, 5.0, 6.0],
         [0.0, 1.0, 2.0, 3.0, 10.0, 4.0, 5.0, 6.0],
-        [0.0, 1.0, 2.0, 3.0, 10.0, 4.0, 5.0, 6.0],
+        [0.0, 1.0, 2.0, 3.0, 7.0, 4.0, 6.0, 6.5],
         [0.0, 1.0, 2.0, 3.0, 10.0, 4.0, 5.0, 6.0],
     ], jnp.float32)
     temperature = jnp.asarray([0.0, 1.0, 1.0, 1.0])
