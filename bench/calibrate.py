@@ -10,10 +10,10 @@ check `run.py` makes of what was served: the greedy gap, the sampled
 tokens' nucleus excess and surprise. For the `--control` seeds also the
 control in the program's place (the greedy gap of the tokens the float8
 reference puts first) and whether the cell's limits find it not correct.
-Each `--fault name:seeds` then plants that fault of `bench/faults.py` and
-reads the same numbers on its seeds. Prints one JSON line per seed; the
-limits lie between the largest sound reading and the smallest control or
-fault reading (PERF.md gives both).
+Each `--fault name:seeds` then plants that fault (`bench/faults.py`, or
+the architecture's `FAULTS`) and reads the same numbers on its seeds.
+Prints one JSON line per seed; the limits lie between the largest sound
+reading and the smallest control or fault reading (PERF.md gives both).
 """
 from __future__ import annotations
 
@@ -36,12 +36,12 @@ def seed_list(spec: str) -> list:
 
 
 def reading(cell, seed: int, seconds: float, control: bool) -> dict:
-    planned = traffic.generate(cell.mix, seed, cell.cfg["vocab"], seconds)
+    planned = traffic.generate(cell.mix, seed, cell.vocab, seconds)
     _, w, eng, _ = run.setup(cell, seed, planned)
     rec = run.drive_cell(eng, cell, planned, seconds)
     del eng
     gc.collect()
-    chk = run.check_served(w, cell.cfg, cell.mix,
+    chk = run.check_served(cell.arch, w, cell.cfg, cell.mix,
                            *run.pick_checked(rec, cell.mix, seed),
                            control=control)
     out = dict(chk, seed=seed, compiles=rec.compiles,
@@ -60,7 +60,8 @@ def main(argv=None) -> int:
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--control", default="")
     ap.add_argument("--fault", action="append", default=[],
-                    help="name:seeds, a fault of bench/faults.py")
+                    help="name:seeds, a fault of bench/faults.py or of "
+                    "the cell's architecture")
     ap.add_argument("--seconds", type=float, default=20.0)
     a = ap.parse_args(argv)
     cell = run.Cell(run.ROOT, a.workload)
@@ -75,7 +76,7 @@ def main(argv=None) -> int:
               flush=True)
     for spec in a.fault:
         name, _, seeds = spec.partition(":")
-        remove = faults.plant(name)
+        remove = faults.plant(name, cell.arch)
         for seed in seed_list(seeds):
             print(json.dumps(dict(reading(cell, seed, a.seconds, False),
                                   fault=name)), flush=True)

@@ -4,12 +4,14 @@
   JAX_PLATFORMS=cpu python3 bench/compile_v5e.py [--workload <cell> ...]
 
 For each cell of BENCHMARK.json: the pooled decode step the engine runs
-(guarded variant, Pallas `ssm_decode` in every layer) at the cell's slots
-and `max_len`, and the bucketed prefill at the largest bucket its traffic
-uses, batch `prefills_per_step`. Compiled by the TPU compiler that ships
-with JAX for one chip of a described `v5e:2x2`; prints each program's
-`memory_analysis()` and whether the decode holds the kernel. Nothing runs,
-so this says nothing of times. The persistent compile cache stays off.
+(guarded variant, with the TPU kernels its architecture takes on the chip,
+`use_tpu_kernels`) at the cell's slots and `max_len`, and the bucketed
+prefill at the largest bucket its traffic uses, batch `prefills_per_step`.
+Compiled by the TPU compiler that ships with JAX for one chip of a
+described `v5e:2x2`; prints each program's `memory_analysis()` and, for
+each kernel of the architecture's `KERNELS`, whether the decode holds it.
+Nothing runs, so this says nothing of times. The persistent compile cache
+stays off.
 """
 from __future__ import annotations
 
@@ -17,11 +19,12 @@ import argparse
 import functools
 import json
 import os
+import re
 import sys
 
 import run
+import trace_reduce
 import traffic
-import weights
 
 
 def _on(sharding, tree):
@@ -39,20 +42,29 @@ def mem(compiled) -> dict:
         "alias_size_in_bytes")}
 
 
+def kernels_held(hlo: str, kernels: dict) -> dict:
+    """For each kernel: whether an instruction of the compiled HLO has a
+    name its trace-op pattern matches (the trace names ops after them)."""
+    names = {trace_reduce.stable_name(n)
+             for n in re.findall(r"%([\w.-]+) = ", hlo)}
+    return {k: any(re.search(op, n) for n in names)
+            for k, (op, _) in kernels.items()}
+
+
 def compile_cell(cell: run.Cell, chip) -> dict:
     import jax
     import jax.numpy as jnp
     import program
     program._import_path()
-    from repro.kernels.ssm_decode import ops
     from repro.models.layers import NOCTX
     from repro.models.model import init_cache, prefill
     from repro.serve.engine import _decode_step_guarded
-    ops._on_tpu = lambda: True           # the decode takes the Pallas kernel
-    mix, mcfg = cell.mix, program.model_config(cell.cfg)
+    arch = cell.arch
+    arch.use_tpu_kernels()
+    mix, mcfg = cell.mix, arch.model_config(cell.cfg)
     B, max_len, K = mix["slots"], mix["max_len"], mix["prefills_per_step"]
     params = _on(chip, jax.eval_shape(
-        lambda: weights.to_program(weights.make_weights(cell.cfg, 0))))
+        lambda: arch.to_program(arch.make_weights(cell.cfg, 0))))
     cache = _on(chip, jax.eval_shape(lambda: jax.tree.map(
         lambda p: p.value, init_cache(mcfg, B, max_len, cache_kind="native",
                                       per_slot=True),
@@ -70,7 +82,8 @@ def compile_cell(cell: run.Cell, chip) -> dict:
                       lengths=s((K,), jnp.int32)).compile()
     return {"cell": cell.name,
             "decode": dict(mem(dec_c), slots=B, max_len=max_len,
-                           kernel="tpu_custom_call" in dec_c.as_text()),
+                           kernels=kernels_held(dec_c.as_text(),
+                                                arch.KERNELS)),
             "prefill": dict(mem(pre_c), batch=K, bucket=bucket)}
 
 

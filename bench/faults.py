@@ -1,36 +1,26 @@
 """Faults planted under the serving program, for the checks that
 `correct` has to fail. Each wraps one function of the program where it is
-looked up at trace time, so an engine built after `plant` runs it:
+looked up at trace time, so an engine built after `plant` runs it. The
+faults of the engine and its sampler serve every architecture:
 
-  * `state_unchanged`: the decode step returns its modal state unchanged;
   * `token_altered`: the per-slot sampler's token is altered where it is
     produced;
   * `wrong_slot`: admission writes a prefilled state into the wrong slot;
-  * `zero_prefill_state`: prefill hands decode a zero modal state;
   * `top_p_skipped`: the sampler draws sampled rows from the whole
     distribution, with no nucleus cut;
   * `temperature_ignored`: the sampler draws sampled rows at temperature 1.
 
 Greedy rows stay greedy under the last two, so only the check of sampled
-requests can see them.
+requests can see them. Each architecture adds the faults of its own state
+(`FAULTS` in `bench/archs/<arch>.py`), in the same form.
 """
 from __future__ import annotations
 
+import importlib
+
 import program
 
-
-def _modules():
-    program._import_path()
-    from repro.models import hyena
-    from repro.serve import scheduler
-    return hyena, scheduler
-
-
-def _state_unchanged(orig):
-    def f(x_re, x_im, *a):
-        y, _, _ = orig(x_re, x_im, *a)
-        return y, x_re, x_im
-    return f
+SCHEDULER = "repro.serve.scheduler"
 
 
 def _token_altered(orig):
@@ -44,13 +34,6 @@ def _wrong_slot(orig):
         import jax.numpy as jnp
         B = pool["pos"].shape[0]
         return orig(pool, multi, jnp.where(slots < B, (slots + 1) % B, slots))
-    return f
-
-
-def _zero_state(orig):
-    def f(dp, u, hcfg, lengths=None):
-        xr, xi = orig(dp, u, hcfg, lengths=lengths)
-        return xr * 0, xi * 0
     return f
 
 
@@ -71,15 +54,19 @@ def _temperature_ignored(orig):
     return f
 
 
-# name -> (module index in _modules(), attribute, wrapper)
-FAULTS = {
-    "state_unchanged": (0, "ssm_decode", _state_unchanged),
-    "token_altered": (1, "sample_token_slots", _token_altered),
-    "wrong_slot": (1, "write_cache_slots", _wrong_slot),
-    "zero_prefill_state": (0, "modal_prefill_state", _zero_state),
-    "top_p_skipped": (1, "sample_token_slots", _top_p_skipped),
-    "temperature_ignored": (1, "sample_token_slots", _temperature_ignored),
+# name -> (program module, attribute, wrapper)
+SHARED = {
+    "token_altered": (SCHEDULER, "sample_token_slots", _token_altered),
+    "wrong_slot": (SCHEDULER, "write_cache_slots", _wrong_slot),
+    "top_p_skipped": (SCHEDULER, "sample_token_slots", _top_p_skipped),
+    "temperature_ignored": (SCHEDULER, "sample_token_slots",
+                            _temperature_ignored),
 }
+
+
+def table(arch) -> dict:
+    """Every fault a cell of this architecture can have."""
+    return {**SHARED, **arch.FAULTS}
 
 
 def clear_programs() -> None:
@@ -93,11 +80,12 @@ def clear_programs() -> None:
     jax.clear_caches()
 
 
-def plant(name: str, setattr_=setattr):
-    """Plant fault `name`; returns a function that removes it. `setattr_`
-    lets a test use its monkeypatch instead."""
-    idx, attr, wrap = FAULTS[name]
-    mod = _modules()[idx]
+def plant(name: str, arch, setattr_=setattr):
+    """Plant fault `name` of `arch`; returns a function that removes it.
+    `setattr_` lets a test use its monkeypatch instead."""
+    modname, attr, wrap = table(arch)[name]
+    program._import_path()
+    mod = importlib.import_module(modname)
     orig = getattr(mod, attr)
     setattr_(mod, attr, wrap(orig))
     clear_programs()

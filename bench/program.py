@@ -1,10 +1,11 @@
-"""Everything the benchmark asks of the system under test, in one place.
+"""What the benchmark asks of the serving path, for every architecture.
 
-The harness reaches the serving path only through these functions: the
-model configuration built from a config file, the continuous-batching
-engine the launcher's `--stream` mode drives (`submit_request` and
-`step()`), its warmup, and the program's compile counter. Nothing of the
-yardstick (traffic, reference, counters, trace reduction) lives here.
+The harness reaches the serving path only through these functions and an
+architecture's module (`bench/archs/<arch>.py`, which builds the model
+configuration and the parameter tree): the continuous-batching engine the
+launcher's `--stream` mode drives (`submit_request` and `step()`), its
+warmup, its counters and the program's compile counter. Nothing of the
+yardstick (traffic, reference, counts, trace reduction) lives here.
 """
 from __future__ import annotations
 
@@ -17,26 +18,6 @@ SRC = Path(__file__).resolve().parents[1] / "src"
 def _import_path() -> None:
     if str(SRC) not in sys.path:
         sys.path.insert(0, str(SRC))
-
-
-def model_config(cfg: dict):
-    """The program's ModelConfig for a MultiHyena config file."""
-    _import_path()
-    from repro.configs.base import HYENA, HyenaConfig, ModelConfig
-    M = cfg["n_filter_heads"]
-    return ModelConfig(
-        name=cfg["name"], family="lcsm", n_layers=cfg["n_layers"],
-        d_model=cfg["d_model"], n_heads=M, n_kv_heads=M,
-        head_dim=cfg["d_model"] // M, d_ff=cfg["d_ff"], vocab=cfg["vocab"],
-        act=cfg["act"], norm=cfg["norm"], pattern=(HYENA,),
-        hyena=HyenaConfig(n_filter_heads=M, filter_order=cfg["filter_order"],
-                          filter_emb=cfg["filter_emb"],
-                          short_conv=cfg["short_conv"],
-                          sine_freq=float(cfg["sine_freq"]),
-                          modulate=bool(cfg.get("modulate", True)),
-                          distill_order=cfg["distill_order"]),
-        tie_embeddings=bool(cfg["tie_embeddings"]), dtype=cfg["dtype"],
-        max_seq=cfg["max_seq"])
 
 
 def enable_compile_cache() -> str:
@@ -101,7 +82,3 @@ def health(eng) -> dict:
     """The engine's own counters of faults and recoveries that are not 0."""
     return {k: v for k, v in eng.resilience.snapshot().items() if v}
 
-
-def state_itemsize(eng) -> int:
-    """Bytes per element of the served modal state."""
-    return int(eng.cache["groups"]["l0"]["x_re"].dtype.itemsize)

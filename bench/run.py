@@ -7,16 +7,21 @@ Everything is found by name from BENCHMARK.json at the root of the
 checkout: the cell names a configuration (`bench/configs/<config>.json`)
 and a traffic mix (`bench/traffic/<traffic>.json`); its correctness limits
 are `bench/limits/<cell>.json`; each metric is read by
-`bench/metrics/<metric>.py`. A new cell or metric is new files and entries.
+`bench/metrics/<metric>.py`. The configuration names its architecture
+(key `"arch"`), the module `bench/archs/<arch>.py`, and the harness reaches
+the architecture only through it: the program's model configuration, the
+weights and their dtype, the plain reference, the FLOP and byte counts,
+the kernels and executable runs to find in the trace, the state's dtype and
+the faults. A new cell, metric or architecture is new files and entries.
 
 A run: weights from the seed on the device (one jitted call), the engine
 that `launch/serve.py --stream --mode distilled` builds, warmup of the
 cell's prompt buckets (set-up ends here), the mix's load for `--seconds`
 (open loop at a fixed rate, or closed loop), then the check of what the
-window served against the float32 reference. With `--trace 1` the
-profiler records a few seconds near the end of the window, and the line
-holds the per-layer metrics and the device's busy time; without, the
-end-to-end metrics.
+window served against the architecture's float32 reference. With
+`--trace 1` the profiler records a few seconds near the end of the window,
+and the line holds the per-layer metrics and the device's busy time;
+without, the end-to-end metrics.
 
 The last line of standard output is one JSON object (`correct`,
 `attempted`, `failed`, `metrics`, `device`, with `--trace 1` also
@@ -52,23 +57,28 @@ import program  # noqa: E402
 import reference  # noqa: E402
 import trace_reduce  # noqa: E402
 import traffic  # noqa: E402
-import weights  # noqa: E402
 import work  # noqa: E402
 
 ROOT = HERE.parent
 NO_CHIP_EXIT = 3
-# Names in the device trace, as `trace_reduce.stable_name` gives them. The
-# Pallas modal decode kernel's custom call is named after the function that
-# calls it (`ssm_decode_pallas` in kernels/ssm_decode). The engine jits its
-# decode and prefill steps as `functools.partial`s, which JAX names
-# `jit__unknown`: a run that holds the kernel is a pooled decode, one that
-# holds none a bucketed prefill.
-SSM_DECODE_OP = r"^ssm_decode_pallas$"
-ENGINE_EXE = r"^jit__unknown$"
 
 
 class NoChip(RuntimeError):
     pass
+
+
+def load_module(path: Path, name: str):
+    """The Python file at `path`, run as a module named `name`."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_arch(root: Path, name: str):
+    """The architecture module `bench/archs/<name>.py` under `root`."""
+    return load_module(Path(root) / "bench" / "archs" / f"{name}.py",
+                       "bench_arch_" + name)
 
 
 class Cell:
@@ -85,6 +95,9 @@ class Cell:
         self.name = name
         conf = {c["name"]: c for c in self.bench["configs"]}[self.cell["config"]]
         self.cfg = self._json(conf["file"])
+        if "arch" not in self.cfg:
+            raise KeyError(f"{conf['file']} names no architecture (\"arch\")")
+        self.arch = load_arch(self.root, self.cfg["arch"])
         self.mix = self._json(f"bench/traffic/{self.cell['traffic']}.json")
         self.limits = self._json(f"bench/limits/{name}.json")
         self.chips = int(self.cell["chips"])
@@ -92,18 +105,18 @@ class Cell:
     def _json(self, rel: str) -> dict:
         return json.loads((self.root / rel).read_text())
 
+    @property
+    def vocab(self) -> int:
+        return self.arch.model_config(self.cfg).vocab
+
     def metrics(self, kind: str) -> list:
         """This cell's `end_to_end` or `per_layer` entries."""
         return [m for m in self.bench[kind]
                 if self.name in m.get("workloads", [self.name])]
 
     def reader(self, metric: str):
-        path = self.root / "bench" / "metrics" / f"{metric}.py"
-        spec = importlib.util.spec_from_file_location(
-            "bench_metric_" + metric.replace(".", "_"), path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
+        return load_module(self.root / "bench" / "metrics" / f"{metric}.py",
+                           "bench_metric_" + metric.replace(".", "_"))
 
 
 def check_devices(chips: int, require_tpu: bool = True) -> list:
@@ -138,11 +151,11 @@ class Context:
     """What a metric reader is given."""
 
     percentile = staticmethod(percentile)
-    work = work
 
     def __init__(self, cell: Cell, rec, seconds, setup_s, warmup_s, peak,
                  state_itemsize, red=None):
         self.cfg, self.mix, self.chips = cell.cfg, cell.mix, cell.chips
+        self.arch = cell.arch
         self.n_slots = cell.mix["slots"]
         self.record, self.seconds = rec, seconds
         self.setup_s, self.warmup_s = setup_s, warmup_s
@@ -202,28 +215,33 @@ class Context:
         return sum(n for t, n in self.record.decoded if self._in_trace(t))
 
     def decode_ms(self):
-        """Device milliseconds per pooled decode run."""
-        if self.trace is None:
+        """Device milliseconds per pooled decode run (the runs the
+        architecture's `DECODE_RUNS` picks)."""
+        if self.trace is None or self.arch.DECODE_RUNS is None:
             return None
-        sec, n = self.trace.runs_of(holding=SSM_DECODE_OP)
+        sec, n = self.trace.runs_of(**self.arch.DECODE_RUNS)
         return 1e3 * sec / n if n else None
 
     def prefill_ms_per_ktok(self):
-        if self.trace is None:
+        """Device milliseconds of bucketed prefill runs (`PREFILL_RUNS`)
+        per 1,000 prompt tokens admitted in the traced span."""
+        if self.trace is None or self.arch.PREFILL_RUNS is None:
             return None
-        sec, n = self.trace.runs_of(name=ENGINE_EXE, lacking=SSM_DECODE_OP)
+        sec, n = self.trace.runs_of(**self.arch.PREFILL_RUNS)
         toks = sum(self.prompts_admitted_in_trace())
         return 1e3 * sec / (toks / 1e3) if n and toks else None
 
-    def ssm_decode_roofline(self):
-        if self.trace is None:
+    def kernel_roofline(self, kernel: str):
+        """The kernel's share of its roofline: the least time its counted
+        work needs on this chip, per call, over its mean device time."""
+        if self.trace is None or kernel not in self.arch.KERNELS:
             return None
-        sec, n = self.trace.ops_of(SSM_DECODE_OP)
+        op, count = self.arch.KERNELS[kernel]
+        sec, n = self.trace.ops_of(op)
         if not n:
             return None
         least, _ = work.roofline_s(
-            work.ssm_decode(self.n_slots, self.cfg, self.state_itemsize),
-            self.peak)
+            count(self.n_slots, self.cfg, self.state_itemsize), self.peak)
         return 100.0 * n * least / sec
 
     def idle_share(self):
@@ -236,9 +254,10 @@ class Context:
         red = self.trace
         if red is None or red.window_s <= 0:
             return None
+        arch = self.arch
         flops = (self.decoded_tokens_in_trace()
-                 * work.decode_flops_per_token(self.cfg)
-                 + sum(work.prefill_flops(self.cfg, T)
+                 * arch.decode_flops_per_token(self.cfg)
+                 + sum(arch.prefill_flops(self.cfg, T)
                        for T in self.prompts_admitted_in_trace()))
         if flops <= 0:
             return None
@@ -272,7 +291,7 @@ def pick_checked(rec, mix: dict, seed: int) -> tuple:
     return greedy, sampled
 
 
-def _reference_rows(w, dims, mix, s, precision="f32"):
+def _reference_rows(arch, w, cfg, mix, s, precision="f32"):
     """The reference's logits at the positions of a served request's
     tokens (teacher-forced: prompt, then the served tokens)."""
     import jax.numpy as jnp
@@ -285,15 +304,15 @@ def _reference_rows(w, dims, mix, s, precision="f32"):
     seq[T:T + n - 1] = toks[:-1]
     served = np.zeros(n_out, np.int32)
     served[:n] = toks
-    ref = reference.logits_at(w, jnp.asarray(seq), T, dims=dims,
-                              max_len=max_len, n_out=n_out,
-                              precision=precision)
+    ref = arch.logits_at(w, jnp.asarray(seq), T, cfg=cfg, max_len=max_len,
+                         n_out=n_out, precision=precision)
     return ref, jnp.asarray(served), n
 
 
-def check_served(w: dict, cfg: dict, mix: dict, greedy: list,
+def check_served(arch, w: dict, cfg: dict, mix: dict, greedy: list,
                  sampled: list = (), control: bool = False) -> dict:
-    """Readings of what the window served, against the float32 reference.
+    """Readings of what the window served, against the architecture's
+    float32 reference (`arch.logits_at`).
 
     Greedy requests: the widest gap by which a served token's logit lies
     below the reference's best (`max_gap`). Sampled requests, each at its
@@ -307,19 +326,18 @@ def check_served(w: dict, cfg: dict, mix: dict, greedy: list,
     place."""
     import jax
     import jax.numpy as jnp
-    dims = reference.Dims.of(cfg)
     out = {"max_gap": 0.0, "tokens": 0, "requests": len(greedy),
            "sampled_requests": len(sampled), "sampled_tokens": 0,
            "nucleus_excess": -1.0, "surprise_z": 0.0}
     if control:
         out["control_gap"] = 0.0
     for s in greedy:
-        ref, served, n = _reference_rows(w, dims, mix, s)
+        ref, served, n = _reference_rows(arch, w, cfg, mix, s)
         out["max_gap"] = max(out["max_gap"],
                              float(jnp.max(reference.gaps(ref, served, n))))
         out["tokens"] += n
         if control:
-            c, _, _ = _reference_rows(w, dims, mix, s, precision="fp8")
+            c, _, _ = _reference_rows(arch, w, cfg, mix, s, "fp8")
             cg = reference.gaps(ref, jnp.argmax(c, axis=-1).astype(jnp.int32),
                                 n)
             out["control_gap"] = max(out["control_gap"], float(jnp.max(cg)))
@@ -327,7 +345,7 @@ def check_served(w: dict, cfg: dict, mix: dict, greedy: list,
         del ref
     dev, var = 0.0, 0.0
     for s in sampled:
-        ref, served, n = _reference_rows(w, dims, mix, s)
+        ref, served, n = _reference_rows(arch, w, cfg, mix, s)
         st = jax.device_get(reference.sampled(
             ref, served, n, jnp.float32(s.plan.temperature),
             jnp.float32(s.plan.top_p)))
@@ -514,10 +532,11 @@ def setup(cell: Cell, seed: int, planned, require_tpu: bool = True):
     import jax
     if require_tpu:
         program.enable_compile_cache()
-    w = weights.make_weights(cell.cfg, seed)
+    arch = cell.arch
+    w = arch.make_weights(cell.cfg, seed)
     jax.block_until_ready(w)
-    mcfg = program.model_config(cell.cfg)
-    eng = program.make_engine(weights.to_program(w), mcfg, cell.mix, seed)
+    eng = program.make_engine(arch.to_program(w), arch.model_config(cell.cfg),
+                              cell.mix, seed)
     t0 = time.monotonic()
     eng.warmup(sorted({len(p.prompt) for p in planned}))
     return devs, w, eng, time.monotonic() - t0
@@ -525,10 +544,10 @@ def setup(cell: Cell, seed: int, planned, require_tpu: bool = True):
 
 def run(cell: Cell, seed: int, seconds: float, trace: bool,
         require_tpu: bool = True, save_trace: str = None) -> dict:
-    planned = traffic.generate(cell.mix, seed, cell.cfg["vocab"], seconds)
+    planned = traffic.generate(cell.mix, seed, cell.vocab, seconds)
     devs, w, eng, warmup_s = setup(cell, seed, planned, require_tpu)
     setup_s = seconds_since_process_start()
-    state_itemsize = program.state_itemsize(eng)
+    state_itemsize = cell.arch.state_itemsize(eng)
     prof = Profiler() if trace else None
     try:
         rec = drive_cell(eng, cell, planned, seconds, prof)
@@ -564,8 +583,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     del eng
     gc.collect()
     t0 = time.monotonic()
-    chk = check_served(w, cell.cfg, cell.mix, *pick_checked(rec, cell.mix,
-                                                            seed))
+    chk = check_served(cell.arch, w, cell.cfg, cell.mix,
+                       *pick_checked(rec, cell.mix, seed))
     due = ctx.attempted()
     failed = sum(s.failed or s.first != s.first for s in due)
     compared = compare(chk, cell.limits, failed)

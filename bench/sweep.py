@@ -58,7 +58,7 @@ def main(argv=None) -> int:
     a = ap.parse_args(argv)
     cell = run.Cell(run.ROOT, a.workload)
     rates = sorted(float(r) for r in a.rates.split(","))
-    vocab = cell.cfg["vocab"]
+    vocab = cell.vocab
     plans = {r: traffic.generate(cell.mix, a.seed + i, vocab, a.seconds,
                                  rate_rps=r) for i, r in enumerate(rates)}
     allp = [p for ps in plans.values() for p in ps]

@@ -1,4 +1,6 @@
-"""bench/run.py end to end on the CPU at a tiny size."""
+"""bench/run.py end to end on the CPU at a tiny size, on a tiny
+MultiHyena and on a tiny GQA Transformer, whose architecture joined the
+benchmark by new files alone."""
 import json
 
 import pytest
@@ -15,7 +17,9 @@ def last_json(capsys):
 
 @pytest.mark.parametrize("cell,trace", [("tiny.chat", 0), ("tiny.chat", 1),
                                         ("tiny.decode", 0),
-                                        ("tiny.decode", 1)])
+                                        ("tiny.decode", 1),
+                                        ("gqa.chat", 0), ("gqa.chat", 1),
+                                        ("gqa.decode", 0), ("gqa.decode", 1)])
 def test_run_prints_result(tiny_root, capsys, cell, trace):
     rc = run.main(["--workload", cell, "--seed", str(2 ** 31 + 7),
                    "--seconds", "1.5", "--trace", str(trace)],

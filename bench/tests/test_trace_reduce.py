@@ -7,8 +7,10 @@ import pytest
 
 import run
 import trace_reduce as tr
+from conftest import ROOT
 
 DATA = Path(__file__).parent / "data" / "v5e_trace.json.gz"
+MH = run.load_arch(ROOT, "multihyena")
 
 
 def ev():
@@ -68,11 +70,11 @@ def test_recorded_chip_trace():
     red = tr.reduce(e, sorted(e.ops))
     assert red.window_s == pytest.approx(0.5, rel=1e-6)
     assert 0 < red.busy_s <= red.window_s
-    dec_s, dec_n = red.runs_of(holding=run.SSM_DECODE_OP)
+    dec_s, dec_n = red.runs_of(**MH.DECODE_RUNS)
     assert dec_n == 16 and 0.15 < dec_s < 0.2
-    pre_s, pre_n = red.runs_of(name=run.ENGINE_EXE, lacking=run.SSM_DECODE_OP)
+    pre_s, pre_n = red.runs_of(**MH.PREFILL_RUNS)
     assert pre_n == 8 and pre_s > 0
-    k_s, k_n = red.ops_of(run.SSM_DECODE_OP)
+    k_s, k_n = red.ops_of(MH.KERNELS["ssm_decode"][0])
     assert k_n == 16 * 18 and 0 < k_s < dec_s
     # self times add up to the busy union, to rounding
     assert sum(red.op_s.values()) == pytest.approx(red.busy_s, rel=1e-3)
