@@ -47,6 +47,7 @@ class SSMConfig:
     head_dim: int = 64
     chunk: int = 256           # SSD chunk length
     n_groups: int = 1
+    conv_bias: bool = False    # a bias on the depthwise conv (Granite-4.0-H)
 
 
 @dataclass(frozen=True)
@@ -108,6 +109,15 @@ class ModelConfig:
     frontend: str = "none"           # none | audio_stub | vision_stub
     frontend_len: int = 1500         # number of frontend embeddings (stub)
     logit_softcap: float = 0.0       # gemma-style final logit soft-capping
+    # Granite-4.0-H: no positions at all (attention without RoPE or a
+    # learned table), and four multipliers; every default leaves a model
+    # without them unchanged
+    nope: bool = False
+    embedding_multiplier: float = 1.0    # token embeddings times this
+    residual_multiplier: float = 1.0     # each mixer and MLP output times this
+    attention_multiplier: Optional[float] = None   # softmax scale; None: 1/sqrt(hd)
+    logits_scaling: float = 1.0          # logits divided by this
+    norm_eps: float = 1e-6               # every RMS/layer norm, gated norm too
     dtype: str = "bfloat16"
     max_seq: int = 131072
 
@@ -115,6 +125,11 @@ class ModelConfig:
     @property
     def hd(self) -> int:
         return self.head_dim if self.head_dim is not None else self.d_model // self.n_heads
+
+    @property
+    def learned_pos(self) -> bool:
+        """A learned absolute position table (rope_theta <= 0, not NoPE)."""
+        return self.rope_theta <= 0.0 and not self.nope
 
     @property
     def blocks(self) -> Tuple[str, ...]:

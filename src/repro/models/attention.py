@@ -27,13 +27,23 @@ def init_attention(key, d: int, n_heads: int, n_kv: int, hd: int):
     }
 
 
-def _gqa_scores(q, k):
-    """q: (B,S,Hq,hd), k: (B,T,Hkv,hd) -> scores (B,Hkv,G,S,T)."""
+def _rope(x, positions, cfg):
+    """RoPE as the config asks; none at all for a NoPE model."""
+    if cfg.nope:
+        return x
+    return apply_rope(x, positions, cfg.rope_theta,
+                      cfg.m_rope_sections if cfg.m_rope else None)
+
+
+def _gqa_scores(q, k, scale=None):
+    """q: (B,S,Hq,hd), k: (B,T,Hkv,hd) -> scores (B,Hkv,G,S,T), times
+    `scale` (None: divided by sqrt(hd))."""
     B, S, Hq, hd = q.shape
     Hkv = k.shape[2]
     G = Hq // Hkv
     qg = q.reshape(B, S, Hkv, G, hd)
-    return jnp.einsum("bskgh,btkh->bkgst", qg, k) / np.sqrt(hd)
+    s = jnp.einsum("bskgh,btkh->bkgst", qg, k)
+    return s / np.sqrt(hd) if scale is None else s * scale
 
 
 def _gqa_out(probs, v):
@@ -54,8 +64,8 @@ def causal_mask(S: int, T: int, offset: int = 0, window: int = 0):
 
 
 def mha(q, k, v, *, causal=True, offset=0, window=0, ctx: ShardCtx = NOCTX,
-        cross=False):
-    scores = _gqa_scores(q, k).astype(jnp.float32)
+        cross=False, scale=None):
+    scores = _gqa_scores(q, k, scale).astype(jnp.float32)
     if causal and not cross:
         m = causal_mask(q.shape[1], k.shape[1], offset, window)
         scores = jnp.where(m[None, None, None], scores, -1e30)
@@ -72,8 +82,10 @@ def mha(q, k, v, *, causal=True, offset=0, window=0, ctx: ShardCtx = NOCTX,
 # repro.kernels.flash_attention is the TPU-tuned variant of the same
 # algorithm.
 # ---------------------------------------------------------------------------
-def chunked_mha(q, k, v, *, causal=True, offset=0, window=0, block=1024):
-    """q: (B,S,Hq,hd); k,v: (B,T,Hkv,hd). Returns (B,S,Hq,hd)."""
+def chunked_mha(q, k, v, *, causal=True, offset=0, window=0, block=1024,
+                scale=None):
+    """q: (B,S,Hq,hd); k,v: (B,T,Hkv,hd). Returns (B,S,Hq,hd). `scale` is
+    the softmax scale (None: 1/sqrt(hd))."""
     from repro import flags
     B, S, Hq, hd = q.shape
     T, Hkv = k.shape[1], k.shape[2]
@@ -84,12 +96,12 @@ def chunked_mha(q, k, v, *, causal=True, offset=0, window=0, block=1024):
         # balances causal over-compute ((nq+1)/nq) against HLO size.
         blk = int(np.clip(S // 4, 1024, 4096))
         return _chunked_mha_unrolled(q, k, v, causal=causal, offset=offset,
-                                     window=window, block=blk)
+                                     window=window, block=blk, scale=scale)
     qb = min(block, S)
     kb = min(block, T)
     assert S % qb == 0 and T % kb == 0, (S, T, block)
     nq, nk = S // qb, T // kb
-    scale = 1.0 / np.sqrt(hd)
+    scale = 1.0 / np.sqrt(hd) if scale is None else scale
     qr = q.reshape(B, nq, qb, Hkv, G, hd)
     kr = k.reshape(B, nk, kb, Hkv, hd)
     vr = v.reshape(B, nk, kb, Hkv, hd)
@@ -145,7 +157,7 @@ def chunked_mha(q, k, v, *, causal=True, offset=0, window=0, block=1024):
 
 
 def _chunked_mha_unrolled(q, k, v, *, causal=True, offset=0, window=0,
-                          block=4096):
+                          block=4096, scale=None):
     """Python-loop flash attention: only causally-needed (i, j) block pairs are
     emitted, so HLO FLOPs match a real blocked causal kernel."""
     B, S, Hq, hd = q.shape
@@ -154,7 +166,7 @@ def _chunked_mha_unrolled(q, k, v, *, causal=True, offset=0, window=0,
     qb = min(block, S)
     kb = min(block, T)
     nq, nk = S // qb, T // kb
-    scale = 1.0 / np.sqrt(hd)
+    scale = 1.0 / np.sqrt(hd) if scale is None else scale
     qr = q.reshape(B, nq, qb, Hkv, G, hd)
     kr = k.reshape(B, nk, kb, Hkv, hd)
     vr = v.reshape(B, nk, kb, Hkv, hd)
@@ -208,10 +220,8 @@ def attention_block(params, x, positions, cfg, *, window=0, ctx: ShardCtx = NOCT
     if cross_kv is None:
         k = jnp.einsum("bsd,dnh->bsnh", x, params["wk"].astype(x.dtype))
         v = jnp.einsum("bsd,dnh->bsnh", x, params["wv"].astype(x.dtype))
-        q = apply_rope(q, positions, cfg.rope_theta,
-                       cfg.m_rope_sections if cfg.m_rope else None)
-        k = apply_rope(k, positions, cfg.rope_theta,
-                       cfg.m_rope_sections if cfg.m_rope else None)
+        q = _rope(q, positions, cfg)
+        k = _rope(k, positions, cfg)
     else:
         k, v = cross_kv
     # TP sharding of attention FLOPs: shard heads when they divide the model
@@ -229,11 +239,12 @@ def attention_block(params, x, positions, cfg, *, window=0, ctx: ShardCtx = NOCT
     k = ctx.cs(k, ("batch", None, "kv_heads", None))
     v = ctx.cs(v, ("batch", None, "kv_heads", None))
     is_causal = causal and cross_kv is None
+    scale = cfg.attention_multiplier
     if q.shape[1] >= 4096 and q.shape[1] % 1024 == 0 and k.shape[1] % 1024 == 0:
-        o = chunked_mha(q, k, v, causal=is_causal, window=window)
+        o = chunked_mha(q, k, v, causal=is_causal, window=window, scale=scale)
     else:
         o = mha(q, k, v, causal=is_causal, window=window, ctx=ctx,
-                cross=cross_kv is not None)
+                cross=cross_kv is not None, scale=scale)
     y = jnp.einsum("bsnh,nhd->bsd", o, params["wo"].astype(x.dtype))
     if return_kv:
         if kv_valid is not None:
@@ -265,10 +276,8 @@ def attention_prefill_chunk(params, cache, x, positions, start, chunk_len,
     q = jnp.einsum("bsd,dnh->bsnh", x, params["wq"].astype(x.dtype))
     k_new = jnp.einsum("bsd,dnh->bsnh", x, params["wk"].astype(x.dtype))
     v_new = jnp.einsum("bsd,dnh->bsnh", x, params["wv"].astype(x.dtype))
-    q = apply_rope(q, positions, cfg.rope_theta,
-                   cfg.m_rope_sections if cfg.m_rope else None)
-    k_new = apply_rope(k_new, positions, cfg.rope_theta,
-                       cfg.m_rope_sections if cfg.m_rope else None)
+    q = _rope(q, positions, cfg)
+    k_new = _rope(k_new, positions, cfg)
     valid = (jnp.arange(C) < chunk_len)[None, :, None, None]
     k_new = jnp.where(valid, k_new, 0).astype(cache["k"].dtype)
     v_new = jnp.where(valid, v_new, 0).astype(cache["v"].dtype)
@@ -277,7 +286,8 @@ def attention_prefill_chunk(params, cache, x, positions, start, chunk_len,
     # chunk queries against the whole buffer: unfilled keys sit strictly in
     # the causal future of every chunk query, so kpos <= qpos masks them
     y = mha(q, k.astype(q.dtype), v.astype(q.dtype), causal=True,
-            offset=start, window=window, ctx=ctx)
+            offset=start, window=window, ctx=ctx,
+            scale=cfg.attention_multiplier)
     y = jnp.einsum("bsnh,nhd->bsd", y, params["wo"].astype(x.dtype))
     return {"k": k, "v": v}, y
 
@@ -302,10 +312,8 @@ def attention_decode_chunk(params, cache, x, pos, active_len, cfg, *,
     q = jnp.einsum("bsd,dnh->bsnh", x, params["wq"].astype(x.dtype))
     k_new = jnp.einsum("bsd,dnh->bsnh", x, params["wk"].astype(x.dtype))
     v_new = jnp.einsum("bsd,dnh->bsnh", x, params["wv"].astype(x.dtype))
-    q = apply_rope(q, positions, cfg.rope_theta,
-                   cfg.m_rope_sections if cfg.m_rope else None)
-    k_new = apply_rope(k_new, positions, cfg.rope_theta,
-                       cfg.m_rope_sections if cfg.m_rope else None)
+    q = _rope(q, positions, cfg)
+    k_new = _rope(k_new, positions, cfg)
     ring = "slot_pos" in cache
     size = cache["k"].shape[1]
     # Attention READS the pre-write cache plus the chunk's own keys as a
@@ -316,7 +324,8 @@ def attention_decode_chunk(params, cache, x, pos, active_len, cfg, *,
     Hkv = cache["k"].shape[2]
     G = q.shape[2] // Hkv
     qg = q.reshape(B, C, Hkv, G, q.shape[-1])
-    scale = 1.0 / np.sqrt(q.shape[-1])
+    scale = (1.0 / np.sqrt(q.shape[-1]) if cfg.attention_multiplier is None
+             else cfg.attention_multiplier)
     s_old = jnp.einsum("bckgh,btkh->bkgct", qg,
                        cache["k"].astype(q.dtype)).astype(jnp.float32) * scale
     # past-segment mask: only positions strictly BEFORE this chunk (also
@@ -408,10 +417,8 @@ def attention_decode(params, cache, x, pos, cfg, *, window=0,
     if cross_kv is None:
         k_new = jnp.einsum("bsd,dnh->bsnh", x, params["wk"].astype(x.dtype))
         v_new = jnp.einsum("bsd,dnh->bsnh", x, params["wv"].astype(x.dtype))
-        q = apply_rope(q, positions, cfg.rope_theta,
-                       cfg.m_rope_sections if cfg.m_rope else None)
-        k_new = apply_rope(k_new, positions, cfg.rope_theta,
-                           cfg.m_rope_sections if cfg.m_rope else None)
+        q = _rope(q, positions, cfg)
+        k_new = _rope(k_new, positions, cfg)
         size = cache["k"].shape[1]
         if per_slot:
             # per-slot write index: scatter one (k, v) row per batch element.
@@ -440,7 +447,8 @@ def attention_decode(params, cache, x, pos, cfg, *, window=0,
         k, v = cross_kv
         new_cache = {}
     T = k.shape[1]
-    scores = _gqa_scores(q, k.astype(q.dtype)).astype(jnp.float32)  # (B,Hkv,G,1,T)
+    scores = _gqa_scores(q, k.astype(q.dtype), cfg.attention_multiplier
+                         ).astype(jnp.float32)                     # (B,Hkv,G,1,T)
     if cross_kv is None:
         pos_b = pos[:, None] if per_slot else pos          # (B,1) | scalar
         if ring:

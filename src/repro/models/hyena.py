@@ -485,7 +485,7 @@ def _short_conv_rows(params, tail, u, active_len):
     `conv_tail_gather(ext, W-1, W-1+j)` yields the tail after ANY j <= C
     tokens, which is how the speculative selection-commit rolls a conv tail
     to the accepted position without a replay."""
-    from repro.models.layers import conv_tail_gather
+    from repro.models.layers import conv_bias, conv_tail_gather
     w = params["w"]
     width = w.shape[0]
     C = u.shape[1]
@@ -494,6 +494,7 @@ def _short_conv_rows(params, tail, u, active_len):
     y = jnp.zeros_like(ext[:, width - 1:, :])
     for i in range(width):
         y = y + ext[:, i:i + C, :] * wc[i]
+    y = conv_bias(params, y)
     if width == 1:
         return tail, y, ext
     new_tail = conv_tail_gather(ext, width - 1, (width - 1) + active_len)

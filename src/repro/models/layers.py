@@ -147,16 +147,24 @@ def init_embed(key, vocab: int, d: int, tie: bool, max_seq: int = 0,
     return p
 
 
-def embed_tokens(params, tokens, ctx: ShardCtx = NOCTX, dtype=jnp.bfloat16):
+def embed_tokens(params, tokens, ctx: ShardCtx = NOCTX, dtype=jnp.bfloat16,
+                 multiplier: float = 1.0):
     x = jnp.take(params["tok"], tokens, axis=0).astype(dtype)
+    if multiplier != 1.0:
+        x = x * jnp.asarray(multiplier, dtype)
     return ctx.cs(x, ("batch", None, "act_embed"))
 
 
-def unembed(params, x, tie: bool, softcap: float = 0.0, ctx: ShardCtx = NOCTX):
+def unembed(params, x, tie: bool, softcap: float = 0.0, ctx: ShardCtx = NOCTX,
+            scaling: float = 1.0):
+    """Logits of x; divided by `scaling` (Granite's logits_scaling), then
+    soft-capped."""
     if tie:
         logits = jnp.einsum("...d,vd->...v", x, params["tok"].astype(x.dtype))
     else:
         logits = jnp.einsum("...d,dv->...v", x, params["unembed"].astype(x.dtype))
+    if scaling != 1.0:
+        logits = logits / jnp.asarray(scaling, logits.dtype)
     if softcap > 0.0:
         logits = softcap * jnp.tanh(logits / softcap)
     return ctx.cs(logits, ("batch", None, "vocab"))
@@ -165,9 +173,19 @@ def unembed(params, x, tie: bool, softcap: float = 0.0, ctx: ShardCtx = NOCTX):
 # ---------------------------------------------------------------------------
 # Depthwise causal short conv (Hyena / Mamba / RG-LRU frontends)
 # ---------------------------------------------------------------------------
-def init_short_conv(key, d: int, width: int):
+def init_short_conv(key, d: int, width: int, bias: bool = False):
     w = jax.random.normal(key, (width, d)) / np.sqrt(width)
-    return {"w": Param(w, ("conv", "act_embed"))}
+    p = {"w": Param(w, ("conv", "act_embed"))}
+    if bias:
+        p["b"] = zeros_init((d,), ("act_embed",))
+    return p
+
+
+def conv_bias(params, y):
+    """y plus the conv's bias where its parameters hold one ("b")."""
+    if "b" not in params:
+        return y
+    return y + params["b"].astype(y.dtype)
 
 
 def apply_short_conv(params, x):
@@ -178,7 +196,7 @@ def apply_short_conv(params, x):
     out = jnp.zeros_like(x)
     for i in range(width):
         out = out + pad[:, i:i + x.shape[1], :] * w[i]
-    return out
+    return conv_bias(params, out)
 
 
 def short_conv_step(params, cache, u):
@@ -188,7 +206,7 @@ def short_conv_step(params, cache, u):
     window = jnp.concatenate([cache, u[:, None, :]], axis=1)  # (B, W, D)
     y = jnp.einsum("bwd,wd->bd", window, w)
     new_cache = window[:, 1:, :] if width > 1 else cache
-    return new_cache, y
+    return new_cache, conv_bias(params, y)
 
 
 def conv_tail_gather(x, width: int, lengths):
@@ -220,6 +238,7 @@ def short_conv_chunk(params, tail, x, chunk_len=None):
     y = jnp.zeros_like(x)
     for i in range(width):
         y = y + ext[:, i:i + C, :] * w[i]
+    y = conv_bias(params, y)
     if width == 1:
         return tail, y
     if chunk_len is None:

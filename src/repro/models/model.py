@@ -90,7 +90,7 @@ def init_params(key, cfg: ModelConfig):
     params: Dict[str, Any] = {
         "embed": init_embed(keys[-1], cfg.vocab, cfg.d_model, cfg.tie_embeddings,
                             max_seq=max(cfg.max_seq, 1),
-                            learned_pos=(cfg.rope_theta <= 0.0)),
+                            learned_pos=cfg.learned_pos),
         "final_norm": init_norm(cfg.norm, cfg.d_model),
         "groups": stack_groups(groups),
     }
@@ -115,6 +115,13 @@ def init_params(key, cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 # Forward (full sequence)
 # ---------------------------------------------------------------------------
+def _residual(x, y, cfg: ModelConfig):
+    """x + y, the branch scaled by the config's residual multiplier."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * jnp.asarray(cfg.residual_multiplier, y.dtype)
+    return x + y
+
+
 def _apply_block(bp, kind: str, x, positions, cfg: ModelConfig, ctx: ShardCtx,
                  *, enc_out=None, moe_impl: str, collect_cache: bool = False,
                  cross_kv_cache=None, cache_kind: str = "native",
@@ -126,7 +133,7 @@ def _apply_block(bp, kind: str, x, positions, cfg: ModelConfig, ctx: ShardCtx,
     padded positions never reach the SSM states, conv tails, or KV caches.
     `filter_len` pins the Hyena filter materialization length (serving).
     """
-    h = apply_norm(bp["norm1"], x, cfg.norm)
+    h = apply_norm(bp["norm1"], x, cfg.norm, cfg.norm_eps)
     cache = None
     window = cfg.window if kind == LOCAL_ATTN else 0
     kv_valid = (None if lengths is None else
@@ -164,9 +171,9 @@ def _apply_block(bp, kind: str, x, positions, cfg: ModelConfig, ctx: ShardCtx,
             y = ssm_mod.rglru_block(bp["mix"], h, cfg, ctx=ctx)
     else:
         raise ValueError(kind)
-    x = ctx.cs(x + y, ("batch", None, "act_embed"))
+    x = ctx.cs(_residual(x, y, cfg), ("batch", None, "act_embed"))
     if "cross" in bp:
-        h = apply_norm(bp["cross_norm"], x, cfg.norm)
+        h = apply_norm(bp["cross_norm"], x, cfg.norm, cfg.norm_eps)
         if cross_kv_cache is not None:
             kv = cross_kv_cache
         else:
@@ -181,12 +188,12 @@ def _apply_block(bp, kind: str, x, positions, cfg: ModelConfig, ctx: ShardCtx,
             cache = {"cross_k": kv[0], "cross_v": kv[1]}
     aux = jnp.zeros((), jnp.float32)
     if cfg.d_ff > 0:
-        h = apply_norm(bp["norm2"], x, cfg.norm)
+        h = apply_norm(bp["norm2"], x, cfg.norm, cfg.norm_eps)
         if cfg.mlp_kind == MLP_MOE:
             y, aux = moe_mod.moe_block(bp["mlp"], h, cfg.moe, impl=moe_impl, ctx=ctx)
         else:
             y = apply_mlp(bp["mlp"], h, cfg.act, ctx=ctx)
-        x = ctx.cs(x + y, ("batch", None, "act_embed"))
+        x = ctx.cs(_residual(x, y, cfg), ("batch", None, "act_embed"))
     return x, aux, cache
 
 
@@ -209,13 +216,14 @@ def forward(params, tokens, cfg: ModelConfig, *, ctx: ShardCtx = NOCTX,
         raise ValueError("lengths (bucketed prefill) is incompatible with "
                          "frontend inputs")
     dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
-    x = embed_tokens(params["embed"], tokens, ctx=ctx, dtype=dtype)
+    x = embed_tokens(params["embed"], tokens, ctx=ctx, dtype=dtype,
+                     multiplier=cfg.embedding_multiplier)
     enc_out = None
     if cfg.enc_dec and frontend is not None:
         enc_out = encode_stack(params, frontend.astype(dtype), cfg, ctx)
     elif frontend is not None:                       # VLM: prepend patch embeds
         x = jnp.concatenate([frontend.astype(dtype), x], axis=1)
-    if cfg.rope_theta <= 0.0:                        # learned absolute positions
+    if cfg.learned_pos:                        # learned absolute positions
         x = x + params["embed"]["pos"][None, :x.shape[1], :].astype(dtype)
     positions = jnp.broadcast_to(jnp.arange(x.shape[1])[None, :],
                                  (x.shape[0], x.shape[1]))
@@ -260,9 +268,10 @@ def forward(params, tokens, cfg: ModelConfig, *, ctx: ShardCtx = NOCTX,
                                filter_len=filter_len)
         aux = aux + a
         rem_caches.append(c)
-    x = apply_norm(params["final_norm"], x, cfg.norm)
+    x = apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
     logits = unembed(params["embed"], x, cfg.tie_embeddings,
-                     softcap=cfg.logit_softcap, ctx=ctx)
+                     softcap=cfg.logit_softcap, ctx=ctx,
+                     scaling=cfg.logits_scaling)
     if collect_cache:
         return logits, aux, (scan_caches, rem_caches)
     return logits, aux
@@ -275,18 +284,18 @@ def encode_stack(params, frontend_emb, cfg: ModelConfig, ctx: ShardCtx):
                                  (x.shape[0], x.shape[1]))
 
     def body(carry, lp):
-        h = apply_norm(lp["norm1"], carry, cfg.norm)
+        h = apply_norm(lp["norm1"], carry, cfg.norm, cfg.norm_eps)
         y = attn_mod.attention_block(lp["mix"], h, positions, cfg, ctx=ctx,
                                      causal=False)
         carry = carry + y
-        h = apply_norm(lp["norm2"], carry, cfg.norm)
+        h = apply_norm(lp["norm2"], carry, cfg.norm, cfg.norm_eps)
         carry = carry + apply_mlp(lp["mlp"], h, cfg.act, ctx=ctx)
         return carry, None
 
     from repro import flags
     n_e = jax.tree.leaves(params["encoder"])[0].shape[0]
     x, _ = jax.lax.scan(body, x, params["encoder"], unroll=flags.scan_unroll(n_e))
-    return apply_norm(params["enc_norm"], x, cfg.norm)
+    return apply_norm(params["enc_norm"], x, cfg.norm, cfg.norm_eps)
 
 
 # ---------------------------------------------------------------------------
@@ -387,7 +396,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, *,
 
 def _decode_block(bp, bc, kind: str, x, pos, cfg: ModelConfig, ctx: ShardCtx,
                   conv_filters=None):
-    h = apply_norm(bp["norm1"], x, cfg.norm)
+    h = apply_norm(bp["norm1"], x, cfg.norm, cfg.norm_eps)
     window = cfg.window if kind == LOCAL_ATTN else 0
     if kind in (ATTN, LOCAL_ATTN):
         kv = {k: bc[k] for k in ("k", "v", "slot_pos") if k in bc}
@@ -423,20 +432,20 @@ def _decode_block(bp, bc, kind: str, x, pos, cfg: ModelConfig, ctx: ShardCtx,
         bc = dict(bc, **sub)
     else:
         raise ValueError(kind)
-    x = x + y
+    x = _residual(x, y, cfg)
     if "cross" in bp:
-        h = apply_norm(bp["cross_norm"], x, cfg.norm)
+        h = apply_norm(bp["cross_norm"], x, cfg.norm, cfg.norm_eps)
         y = attn_mod.attention_block(bp["cross"], h,
                                      jnp.zeros((x.shape[0], 1), jnp.int32), cfg,
                                      ctx=ctx, cross_kv=(bc["cross_k"], bc["cross_v"]))
         x = x + y
     if cfg.d_ff > 0:
-        h = apply_norm(bp["norm2"], x, cfg.norm)
+        h = apply_norm(bp["norm2"], x, cfg.norm, cfg.norm_eps)
         if cfg.mlp_kind == MLP_MOE:
             y, _ = moe_mod.moe_block(bp["mlp"], h, cfg.moe, ctx=ctx)
         else:
             y = apply_mlp(bp["mlp"], h, cfg.act, ctx=ctx)
-        x = x + y
+        x = _residual(x, y, cfg)
     return bc, x
 
 
@@ -452,8 +461,9 @@ def decode_step(params, cache, tokens, cfg: ModelConfig, *, ctx: ShardCtx = NOCT
     """
     dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
     pos = jnp.asarray(cache["pos"], jnp.int32)
-    x = embed_tokens(params["embed"], tokens, ctx=ctx, dtype=dtype)
-    if cfg.rope_theta <= 0.0:
+    x = embed_tokens(params["embed"], tokens, ctx=ctx, dtype=dtype,
+                     multiplier=cfg.embedding_multiplier)
+    if cfg.learned_pos:
         pe = params["embed"]["pos"]
         if pos.ndim == 1:
             x = x + jnp.take(pe, jnp.clip(pos, 0, pe.shape[0] - 1),
@@ -490,9 +500,10 @@ def decode_step(params, cache, tokens, cfg: ModelConfig, *, ctx: ShardCtx = NOCT
                                   conv_filters=rem_filters.get(i))
             rem.append(bc)
         new_cache["rem"] = rem
-    x = apply_norm(params["final_norm"], x, cfg.norm)
+    x = apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
     logits = unembed(params["embed"], x, cfg.tie_embeddings,
-                     softcap=cfg.logit_softcap, ctx=ctx)
+                     softcap=cfg.logit_softcap, ctx=ctx,
+                     scaling=cfg.logits_scaling)
     return new_cache, logits
 
 
@@ -510,7 +521,7 @@ def decode_step(params, cache, tokens, cfg: ModelConfig, *, ctx: ShardCtx = NOCT
 def _decode_chunk_block(bp, bc, kind: str, x, pos, active_len,
                         cfg: ModelConfig, ctx: ShardCtx, conv_filters=None,
                         collect_states: bool = False):
-    h = apply_norm(bp["norm1"], x, cfg.norm)
+    h = apply_norm(bp["norm1"], x, cfg.norm, cfg.norm_eps)
     window = cfg.window if kind == LOCAL_ATTN else 0
     aux = {}
     if kind in (ATTN, LOCAL_ATTN):
@@ -559,14 +570,14 @@ def _decode_chunk_block(bp, bc, kind: str, x, pos, active_len,
         bc = dict(bc, **sub)
     else:
         raise ValueError(kind)
-    x = x + y
+    x = _residual(x, y, cfg)
     if cfg.d_ff > 0:
-        h = apply_norm(bp["norm2"], x, cfg.norm)
+        h = apply_norm(bp["norm2"], x, cfg.norm, cfg.norm_eps)
         if cfg.mlp_kind == MLP_MOE:
             y, _ = moe_mod.moe_block(bp["mlp"], h, cfg.moe, ctx=ctx)
         else:
             y = apply_mlp(bp["mlp"], h, cfg.act, ctx=ctx)
-        x = x + y
+        x = _residual(x, y, cfg)
     if collect_states:
         return bc, x, aux
     return bc, x
@@ -608,8 +619,9 @@ def decode_chunk(params, cache, tokens, cfg: ModelConfig, *, active_len,
         raise ValueError("collect_states requires a pure distilled-Hyena "
                          "arch (see supports_state_select)")
     active_len = jnp.asarray(active_len, jnp.int32)
-    x = embed_tokens(params["embed"], tokens, ctx=ctx, dtype=dtype)
-    if cfg.rope_theta <= 0.0:                    # learned absolute positions
+    x = embed_tokens(params["embed"], tokens, ctx=ctx, dtype=dtype,
+                     multiplier=cfg.embedding_multiplier)
+    if cfg.learned_pos:                    # learned absolute positions
         pe = params["embed"]["pos"]
         positions = pos[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
         x = x + jnp.take(pe, jnp.clip(positions, 0, pe.shape[0] - 1),
@@ -660,9 +672,10 @@ def decode_chunk(params, cache, tokens, cfg: ModelConfig, *, active_len,
         aux["rem"] = rem_aux
     if not need_logits:
         return new_cache, None
-    x = apply_norm(params["final_norm"], x, cfg.norm)
+    x = apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
     logits = unembed(params["embed"], x, cfg.tie_embeddings,
-                     softcap=cfg.logit_softcap, ctx=ctx)
+                     softcap=cfg.logit_softcap, ctx=ctx,
+                     scaling=cfg.logits_scaling)
     if collect_states:
         return new_cache, logits, aux
     return new_cache, logits
@@ -1000,7 +1013,7 @@ def _prefill_chunk_block(bp, bc, kind: str, x, positions, start, chunk_len,
                          cfg: ModelConfig, max_len: int, ctx: ShardCtx, *,
                          conv_filters=None, cache_kind: str = "native"):
     """One block over one prompt chunk. Mirrors _decode_block's structure."""
-    h = apply_norm(bp["norm1"], x, cfg.norm)
+    h = apply_norm(bp["norm1"], x, cfg.norm, cfg.norm_eps)
     window = cfg.window if kind == LOCAL_ATTN else 0
     if kind in (ATTN, LOCAL_ATTN):
         sub = {k: bc[k] for k in ("k", "v")}
@@ -1031,14 +1044,14 @@ def _prefill_chunk_block(bp, bc, kind: str, x, positions, start, chunk_len,
     else:
         raise ValueError(kind)
     bc = dict(bc, **sub)
-    x = x + y
+    x = _residual(x, y, cfg)
     if cfg.d_ff > 0:
-        h = apply_norm(bp["norm2"], x, cfg.norm)
+        h = apply_norm(bp["norm2"], x, cfg.norm, cfg.norm_eps)
         if cfg.mlp_kind == MLP_MOE:
             y, _ = moe_mod.moe_block(bp["mlp"], h, cfg.moe, ctx=ctx)
         else:
             y = apply_mlp(bp["mlp"], h, cfg.act, ctx=ctx)
-        x = x + y
+        x = _residual(x, y, cfg)
     return bc, x
 
 
@@ -1062,8 +1075,9 @@ def prefill_from_cache(params, cache, tokens, start_pos, cfg: ModelConfig,
     chunk_len = jnp.asarray(chunk_len, jnp.int32)
     start = jnp.asarray(start_pos, jnp.int32)
     dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
-    x = embed_tokens(params["embed"], tokens, ctx=ctx, dtype=dtype)
-    if cfg.rope_theta <= 0.0:                    # learned absolute positions
+    x = embed_tokens(params["embed"], tokens, ctx=ctx, dtype=dtype,
+                     multiplier=cfg.embedding_multiplier)
+    if cfg.learned_pos:                    # learned absolute positions
         pe = params["embed"]["pos"]
         x = x + jax.lax.dynamic_slice_in_dim(pe, start, C,
                                              axis=0)[None].astype(dtype)
@@ -1100,9 +1114,10 @@ def prefill_from_cache(params, cache, tokens, start_pos, cfg: ModelConfig,
             rem.append(bc)
         new_cache["rem"] = rem
     x = jax.lax.dynamic_slice_in_dim(x, chunk_len - 1, 1, axis=1)
-    x = apply_norm(params["final_norm"], x, cfg.norm)
+    x = apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
     logits = unembed(params["embed"], x, cfg.tie_embeddings,
-                     softcap=cfg.logit_softcap, ctx=ctx)
+                     softcap=cfg.logit_softcap, ctx=ctx,
+                     scaling=cfg.logits_scaling)
     return new_cache, logits[:, 0, :]
 
 
@@ -1347,6 +1362,21 @@ def slot_health(cache, logits, bound):
         add_block(rc, batch_axis=0)
     m = jnp.max(jnp.abs(jnp.concatenate(parts, axis=1)), axis=1)
     return m <= 1.0
+
+
+def state_bytes(cache) -> Dict[str, int]:
+    """Resident bytes of a pooled cache by kind of state. `kv`: the buffers
+    that grow with max_len (attention k/v and ring positions, the cached-
+    conv k.v history and its FutureFill buffer, cross-attention k/v);
+    `recurrent`: the fixed-size per-slot states (SSM and modal states, conv
+    tails, epoch counters). The position vector is neither."""
+    out = {"recurrent": 0, "kv": 0}
+    for c in list(cache["groups"].values()) + list(cache.get("rem") or []):
+        for k, v in c.items():
+            kind = ("kv" if k in _SEQ_KEYS + ("fut", "cross_k", "cross_v")
+                    else "recurrent")
+            out[kind] += int(v.size) * v.dtype.itemsize
+    return out
 
 
 def reset_cache_slot(pool, slot):

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.distributed.sharding import Param
+from repro.kernels.mamba2_decode.ops import mamba2_state_step
 from repro.models.layers import (
     NOCTX, ShardCtx, apply_short_conv, conv_tail_gather, dense_init,
     init_short_conv, short_conv_chunk, short_conv_step,
@@ -33,7 +34,7 @@ def init_mamba2_block(key, cfg):
         # fused input projection: [z(di), x(di), B(G*N), C(G*N), dt(H)]
         "in_proj": dense_init(k1, (d, 2 * di + 2 * G * s.d_state + H),
                               ("embed", "mlp"), in_dim=d),
-        "conv": init_short_conv(k2, conv_dim, s.d_conv),
+        "conv": init_short_conv(k2, conv_dim, s.d_conv, bias=s.conv_bias),
         "A_log": Param(jnp.log(jnp.linspace(1.0, 16.0, H)), ("heads",)),
         "D": Param(jnp.ones((H,)), ("heads",)),
         "dt_bias": Param(jnp.log(jnp.exp(jnp.linspace(1e-3, 0.1, H)) - 1.0 + 1e-9),
@@ -50,6 +51,15 @@ def _split_mamba_proj(proj, cfg):
     G, N = s.n_groups, s.d_state
     z, xBC, dt = jnp.split(proj, [di, 2 * di + 2 * G * N], axis=-1)
     return z, xBC, dt, di, H, G, N
+
+
+def gated_rms_norm(y, z, scale, eps: float, dtype):
+    """Mamba-2's gated RMSNorm: y * silu(z), normalised in float32 over the
+    inner width and scaled, in `dtype`."""
+    y = y * jax.nn.silu(z)
+    var = jnp.mean(jnp.square(y.astype(jnp.float32)), axis=-1, keepdims=True)
+    return (y.astype(jnp.float32) * jax.lax.rsqrt(var + eps) *
+            scale).astype(dtype)
 
 
 def _segsum(a):
@@ -141,11 +151,7 @@ def mamba2_block(params, x, cfg, *, ctx: ShardCtx = NOCTX, return_state=False,
                            C_.astype(jnp.float32), s.chunk)
     y = y + params["D"][None, None, :, None] * xh
     y = y.reshape(Bsz, S, di).astype(x.dtype)
-    # gated RMSNorm (Mamba-2)
-    y = y * jax.nn.silu(z)
-    var = jnp.mean(jnp.square(y.astype(jnp.float32)), axis=-1, keepdims=True)
-    y = (y.astype(jnp.float32) * jax.lax.rsqrt(var + 1e-6) *
-         params["norm_scale"]).astype(x.dtype)
+    y = gated_rms_norm(y, z, params["norm_scale"], cfg.norm_eps, x.dtype)
     out = jnp.einsum("bse,ed->bsd", y, params["out_proj"].astype(x.dtype))
     if return_state:
         w = s.d_conv - 1
@@ -181,17 +187,9 @@ def mamba2_decode(params, cache, x, cfg, *, ctx: ShardCtx = NOCTX):
     A = -jnp.exp(params["A_log"])
     a = jnp.exp(dt * A)                                                # (B,H)
     xh = xs.reshape(Bsz, H, s.head_dim).astype(jnp.float32)
-    rep = H // G
-    Bh = jnp.repeat(B_, rep, axis=1)                                   # (B,H,N)
-    Ch = jnp.repeat(C_, rep, axis=1)
-    h = cache["ssm"] * a[..., None, None] + \
-        jnp.einsum("bhn,bhp->bhpn", Bh, xh * dt[..., None])
-    y = jnp.einsum("bhpn,bhn->bhp", h, Ch) + params["D"][None, :, None] * xh
+    y, h = mamba2_state_step(cache["ssm"], xh, dt, a, B_, C_, params["D"])
     y = y.reshape(Bsz, di).astype(x.dtype)
-    y = y * jax.nn.silu(z)
-    var = jnp.mean(jnp.square(y.astype(jnp.float32)), axis=-1, keepdims=True)
-    y = (y.astype(jnp.float32) * jax.lax.rsqrt(var + 1e-6) *
-         params["norm_scale"]).astype(x.dtype)
+    y = gated_rms_norm(y, z, params["norm_scale"], cfg.norm_eps, x.dtype)
     out = jnp.einsum("be,ed->bd", y, params["out_proj"].astype(x.dtype))
     return {"conv": conv_cache, "ssm": h}, out[:, None, :]
 
@@ -225,10 +223,7 @@ def mamba2_decode_chunk(params, cache, x, active_len, cfg, *,
                            initial_state=cache["ssm"])
     y = y + params["D"][None, None, :, None] * xh
     y = y.reshape(Bsz, C, di).astype(x.dtype)
-    y = y * jax.nn.silu(z)
-    var = jnp.mean(jnp.square(y.astype(jnp.float32)), axis=-1, keepdims=True)
-    y = (y.astype(jnp.float32) * jax.lax.rsqrt(var + 1e-6) *
-         params["norm_scale"]).astype(x.dtype)
+    y = gated_rms_norm(y, z, params["norm_scale"], cfg.norm_eps, x.dtype)
     out = jnp.einsum("bse,ed->bsd", y, params["out_proj"].astype(x.dtype))
     return {"conv": new_tail.astype(jnp.float32),
             "ssm": state.astype(jnp.float32)}, out
@@ -282,10 +277,7 @@ def mamba2_prefill_chunk(params, cache, x, chunk_len, cfg, *,
                            initial_state=cache["ssm"])
     y = y + params["D"][None, None, :, None] * xh
     y = y.reshape(Bsz, C, di).astype(x.dtype)
-    y = y * jax.nn.silu(z)
-    var = jnp.mean(jnp.square(y.astype(jnp.float32)), axis=-1, keepdims=True)
-    y = (y.astype(jnp.float32) * jax.lax.rsqrt(var + 1e-6) *
-         params["norm_scale"]).astype(x.dtype)
+    y = gated_rms_norm(y, z, params["norm_scale"], cfg.norm_eps, x.dtype)
     out = jnp.einsum("bse,ed->bsd", y, params["out_proj"].astype(x.dtype))
     return {"conv": new_tail.astype(jnp.float32),
             "ssm": state.astype(jnp.float32)}, out

@@ -68,7 +68,7 @@ from repro.models.layers import NOCTX, ShardCtx
 from repro.models.model import (gather_cache_rows, init_cache,
                                 init_prefill_cache,
                                 materialize_conv_filters, modal_state_bound,
-                                reset_cache_slot, slot_health,
+                                reset_cache_slot, slot_health, state_bytes,
                                 write_cache_slot, write_cache_slots)
 from repro.serve.faults import FaultError, corrupt_cache_slot, drift_cache_slot
 from repro.serve.metrics import (DRIFT_BUCKETS, MetricsRegistry,
@@ -564,8 +564,15 @@ class ContinuousBatchingEngine:
                                         "spec_accepted": 0,
                                         "spec_slot_rounds": 0,
                                         "spec_window_syncs": 0,
-                                        "sampled_decode_steps": 0}
+                                        "sampled_decode_steps": 0,
+                                        "state_bytes_recurrent": 0,
+                                        "state_bytes_kv": 0}
         self.stats.update((stat_key(n), 0.0) for n in SPANS)
+        self._g_state_bytes = {
+            kind: _m.gauge(f"serve_state_bytes_{kind}",
+                           help=f"resident {kind} state bytes of the pool")
+            for kind in ("recurrent", "kv")}
+        self._note_state_bytes()
         # resident requests with temperature > 0: a decode dispatch runs the
         # sampler's top-k/top-p filter iff this is nonzero
         self._n_sampled = 0
@@ -670,6 +677,14 @@ class ContinuousBatchingEngine:
             return vals, None
         sh = tree_shardings(vals, slot_axes(axes), SLOT_RULES, self.mesh)
         return jax.device_put(vals, sh), sh
+
+    def _note_state_bytes(self) -> None:
+        """The serving pool's resident bytes by kind of state
+        (`models.model.state_bytes`): `state_bytes_recurrent` and
+        `state_bytes_kv` in the stats dict and as registry gauges."""
+        for kind, n in state_bytes(self.cache).items():
+            self.stats[f"state_bytes_{kind}"] = n
+            self._g_state_bytes[kind].set(n)
 
     def _replicate(self, tree):
         """Pin a tree (params, long filters) replicated across the mesh."""
@@ -1955,6 +1970,7 @@ class ContinuousBatchingEngine:
         kind = _MODE_KINDS[target]
         self._cache_kind = kind
         self.cache, self._cache_sh = self._make_pool(self.cfg, kind)
+        self._note_state_bytes()
         self._conv_filters = self._replicate(
             materialize_conv_filters(self.params, self.cfg, self.max_len))
         self._chunk_filters = self._conv_filters

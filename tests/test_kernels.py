@@ -117,3 +117,38 @@ def test_chunked_mha_matches_ref_paths():
         c2 = _chunked_mha_unrolled(q, k, v, causal=True, window=w, block=128)
         np.testing.assert_allclose(np.asarray(c1), np.asarray(ref), atol=2e-5)
         np.testing.assert_allclose(np.asarray(c2), np.asarray(ref), atol=2e-5)
+
+
+@pytest.mark.parametrize("B,H,G,P,N,bb,hb", [
+    (4, 16, 1, 16, 128, 2, 8),
+    (3, 8, 1, 64, 128, 1, 8),
+    (2, 32, 4, 64, 128, 1, 8),       # heads of four groups share B and C
+    (8, 16, 2, 16, 32, None, None),  # default blocks
+])
+def test_mamba2_decode_sweep(B, H, G, P, N, bb, hb):
+    from repro.kernels.mamba2_decode.mamba2_decode import mamba2_decode_pallas
+    from repro.kernels.mamba2_decode.ref import mamba2_decode_ref
+    ks = jax.random.split(jax.random.PRNGKey(B * H + G), 6)
+    h = jax.random.normal(ks[0], (B, H, P, N))
+    x = jax.random.normal(ks[1], (B, H, P))
+    dt = jax.random.uniform(ks[2], (B, H), minval=1e-3, maxval=0.1)
+    decay = jnp.exp(-dt * jax.random.uniform(ks[3], (B, H), minval=1.0,
+                                             maxval=16.0))
+    Bm, Cm = (jax.random.normal(k, (B, G, N)) for k in jax.random.split(ks[4]))
+    D = jax.random.normal(ks[5], (H,))
+    ref = mamba2_decode_ref(h, x, dt, decay, Bm, Cm, D)
+    out = mamba2_decode_pallas(h, x, dt, decay, Bm, Cm, D, bb=bb, hb=hb,
+                               interpret=True)
+    for r, o in zip(ref, out):
+        np.testing.assert_allclose(np.asarray(o), np.asarray(r), rtol=1e-5,
+                                   atol=1e-5)
+
+
+def test_mamba2_decode_rejects_untileable_block():
+    from repro.kernels.mamba2_decode.mamba2_decode import mamba2_decode_pallas
+    h = jnp.zeros((2, 16, 8, 128))
+    v = jnp.zeros((2, 16))
+    with pytest.raises(ValueError):
+        mamba2_decode_pallas(h, jnp.zeros((2, 16, 8)), v, v,
+                             jnp.zeros((2, 1, 128)), jnp.zeros((2, 1, 128)),
+                             jnp.zeros((16,)), hb=4, interpret=True)

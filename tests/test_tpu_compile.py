@@ -54,6 +54,19 @@ def test_ssm_decode_kernel_compiles(one_chip, B, C):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("B", [8, 32])
+def test_mamba2_decode_kernel_compiles(one_chip, B):
+    """Granite-4.0-H's Mamba-2 widths: 64 heads of 64 x 128 state, one
+    group."""
+    from repro.kernels.mamba2_decode.mamba2_decode import mamba2_decode_pallas
+    H, P, N, f32 = 64, 64, 128, jnp.float32
+    shapes = _on(one_chip, [jax.ShapeDtypeStruct(s, f32) for s in (
+        (B, H, P, N), (B, H, P), (B, H), (B, H), (B, 1, N), (B, 1, N), (H,))])
+    fn = jax.jit(functools.partial(mamba2_decode_pallas, interpret=False))
+    compiled = fn.lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_multihyena_decode_step_compiles_with_kernel(one_chip, monkeypatch):
     """Full-width MultiHyena-153M pooled decode (8 slots) takes the Pallas
     kernel on a TPU and fits the chip."""
